@@ -37,11 +37,7 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 python -m pytest -x -q
 python scripts/check_docs_refs.py
-if python -c "import jax" >/dev/null 2>&1; then
-  REPRO_BACKEND=jax python -m pytest tests/test_backend.py -q
-else
-  echo "ci: jax unavailable — skipping the REPRO_BACKEND=jax smoke leg"
-fi
+REPRO_BACKEND=jax python -m pytest tests/test_backend.py -q
 python -m benchmarks.bench_scheduler --smoke --repeat-best-of 2 \
   --out BENCH_scheduler_smoke.json
 # traced smoke: the same grid with observability on (REPRO_TRACE=1 +

@@ -9,7 +9,7 @@ abstracts *where* that arithmetic runs:
     operation is byte-for-byte the pre-backend code path, preserving the
     repo's bit-parity guarantee against ``core/_reference.py``;
   * ``jax``   — the ledger lives as a device-resident ``jax.Array``
-    (float64 via scoped ``jax.experimental.enable_x64``), commits/releases
+    (float64 via the scoped ``jax.enable_x64(True)``), commits/releases
     are functional ``.at[]`` updates, and repricing + free-capacity
     tensors are jit-compiled on device. Host syncs happen at explicit,
     version-cached points only: when an admission decision needs the

@@ -1,7 +1,7 @@
 """Device-resident jax backend for the ledger and pricing tensors.
 
 The (T, H, R) ledger is a float64 ``jax.Array`` (double precision via
-scoped ``jax.experimental.enable_x64`` — the global x64 flag is never
+the scoped ``jax.enable_x64(True)`` context — the global x64 flag is never
 flipped, so the rest of the repo's float32 jax code is unaffected).
 Mutations are functional ``.at[]`` updates; the two hot derived tensors —
 ``free_tensor`` (C - rho) and ``price_tensor`` (Eq. 12 over the whole
@@ -21,15 +21,44 @@ masked-reduction kernel when running on TPU (or when forced via
 slow, test-only). The release clamp never asserts on this backend (the
 assert would force a device sync per release); the clamp itself is
 preserved, and the invariant is covered by the parity tests.
+
+Building the backend on a TPU configures JAX's persistent compilation cache
+(``configure_compile_cache``): ``JAX_COMPILATION_CACHE_DIR`` when set,
+else the fixed ``<checkout>/.jax_cache``, keeping even sub-second jits.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Dict, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from . import ArrayBackend
+
+#: default persistent compile cache: a fixed path inside the checkout (the
+#: path is part of the cache key, so it must not move between runs)
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def configure_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at a stable directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` (read by JAX itself) wins when set;
+    otherwise the cache lives at ``<checkout>/.jax_cache``. The minimum
+    compile time to persist is lowered to zero: the scheduler's jits are
+    many and each compiles in well under JAX's default one second.
+
+    Only a TPU process is configured: XLA:CPU reloads its cached
+    executables with a host-feature mismatch error on every hit, and the
+    CPU compiles are cheap."""
+    if jax.default_backend() != "tpu":
+        return
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 class JaxBackend(ArrayBackend):
@@ -37,19 +66,7 @@ class JaxBackend(ArrayBackend):
     is_device = True
 
     def __init__(self):
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-        except Exception as e:  # pragma: no cover - container always has jax
-            raise RuntimeError(
-                "REPRO_BACKEND=jax requires a working jax install "
-                f"(import failed: {type(e).__name__}: {e}); "
-                "use the default numpy backend instead"
-            ) from e
-        self._jax = jax
-        self._jnp = jnp
-        self._x64 = enable_x64
+        configure_compile_cache()
         self.trace_counts: Dict[str, int] = {
             "free_tensor": 0, "price_tensor": 0,
         }
@@ -86,8 +103,8 @@ class JaxBackend(ArrayBackend):
 
     # ---- array lifecycle ------------------------------------------------
     def zeros(self, shape):
-        with self._x64():
-            return self._jnp.zeros(shape, dtype=self._jnp.float64)
+        with jax.enable_x64(True):
+            return jnp.zeros(shape, dtype=jnp.float64)
 
     def to_host(self, arr) -> np.ndarray:
         return np.asarray(arr)
@@ -127,11 +144,10 @@ class JaxBackend(ArrayBackend):
         # updates would copy the whole (T, H, R) ledger once per machine
         if not needs:
             return used
-        jnp = self._jnp
         hs = np.array([h for h, _ in needs], dtype=np.int64)
         vecs = np.stack([need for _, need in needs])
         hs, vecs = self._pad_scatter(hs, vecs, neutral_vec=True)
-        with self._x64():
+        with jax.enable_x64(True):
             return self._scatter_add(used, np.int64(t), hs,
                                      jnp.asarray(vecs))
 
@@ -142,17 +158,15 @@ class JaxBackend(ArrayBackend):
         # duplicate set-scatters of equal values are order-independent
         if not needs:
             return used
-        jnp = self._jnp
         hs = np.array([h for h, _ in needs], dtype=np.int64)
         vecs = np.stack([need for _, need in needs])
         hs, vecs = self._pad_scatter(hs, vecs, neutral_vec=False)
-        with self._x64():
+        with jax.enable_x64(True):
             return self._scatter_sub(used, np.int64(t), hs,
                                      jnp.asarray(vecs))
 
     def ledger_advance(self, used, steps: int):
-        jnp = self._jnp
-        with self._x64():
+        with jax.enable_x64(True):
             T = used.shape[0]
             k = min(steps, T)
             if k >= T:
@@ -162,43 +176,39 @@ class JaxBackend(ArrayBackend):
 
     # ---- derived tensors ------------------------------------------------
     def free_tensor(self, used, cap: np.ndarray):
-        with self._x64():
+        with jax.enable_x64(True):
             return self._free_jit(used, cap)
 
     def price_tensor(self, used, cap: np.ndarray, u: np.ndarray, L: float):
-        with self._x64():
+        with jax.enable_x64(True):
             return self._price_jit(used, cap, u, np.float64(L))
 
     def oversubscribed(self, used, cap: np.ndarray, tol: float) -> bool:
-        with self._x64():
-            over = used - self._jnp.asarray(cap)[None, :, :]
+        with jax.enable_x64(True):
+            over = used - jnp.asarray(cap)[None, :, :]
             return bool((over > tol).any())
+
+    @staticmethod
+    def _price_kernel() -> Optional[str]:
+        kernel = os.environ.get("REPRO_PRICE_KERNEL", "").strip() or None
+        if kernel is None and jax.default_backend() == "tpu":
+            kernel = "pallas"
+        return kernel
 
     def snapshot_bundle(self, price_row, free_row, wdem, sdem, gamma):
         from ..kernels.pricing import price_bundle
-        kernel = os.environ.get("REPRO_PRICE_KERNEL", "").strip() or None
-        if kernel is None and self._jax.default_backend() == "tpu":
-            kernel = "pallas"
-        with self._x64():
+        with jax.enable_x64(True):
             return price_bundle(price_row, free_row, wdem, sdem, gamma,
-                                backend=kernel)
+                                backend=self._price_kernel())
 
     def snapshot_bundle_batch(self, price_ops, free_ops, wdem, sdem, gamma):
         from ..kernels.pricing import price_bundle_batch
-        kernel = os.environ.get("REPRO_PRICE_KERNEL", "").strip() or None
-        if kernel is None and self._jax.default_backend() == "tpu":
-            kernel = "pallas"
-        with self._x64():
+        with jax.enable_x64(True):
             return price_bundle_batch(price_ops, free_ops, wdem, sdem,
-                                      gamma, backend=kernel)
+                                      gamma, backend=self._price_kernel())
 
     def minplus_default(self) -> Optional[str]:
-        try:
-            if self._jax.default_backend() == "tpu":
-                return "pallas"
-        except Exception:
-            pass
-        return None
+        return "pallas" if jax.default_backend() == "tpu" else None
 
     def lp_solver_default(self) -> str:
         # the LP solve stays host-side float64 under the jax backend too

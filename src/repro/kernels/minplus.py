@@ -14,9 +14,9 @@ the diagonal). Three implementations, all returning the same values:
     reduction; the default CPU path;
   * ``minplus_pallas``  — a Pallas TPU kernel of the tropical vec-mat
     product (broadcast add + lane-min reduce on the VPU), padded to the
-    float32/float64 tile grid. Off-TPU it runs in interpret mode; any
-    import/lowering failure falls back to the NumPy path (mirroring the
-    rmsnorm/ops kernel pattern).
+    float32 tile grid and tiled over ``ROW_TILE``-row blocks up to
+    ``MAX_P`` padded states. Off-TPU it runs in interpret mode. A
+    lowering or compile failure raises: nothing falls back to NumPy.
 
 Besides the min values every implementation returns the DP ``choice`` array
 (-1 for an unreachable state). Scalar and NumPy share the exact contract —
@@ -34,10 +34,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from ..obs.metrics import warn_once_event
-
 _INF = float("inf")
-_pallas_broken: Optional[str] = None  # first failure reason, warn once
 
 
 def minplus_scalar(
@@ -110,23 +107,47 @@ def minplus_numpy(
 
 
 # ----------------------------------------------------------------- pallas
-def _pallas_minplus_call(A, b, interpret: bool):
-    """cur[u] = min_v A[u, v] + b[v] on padded (P, P)/(1, P) operands."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
+#: rows of the Toeplitz operand per grid step (one 128-lane output block)
+ROW_TILE = 128
+#: largest padded width the kernel takes: one (ROW_TILE, MAX_P) f32 row
+#: block is 4 MiB, which with double buffering and the broadcast-add
+#: temporary stays inside the default scoped VMEM (checked by an ahead-of-
+#: time compile for a described v5e in ``tests/test_tpu_compile.py``)
+MAX_P = 8192
+#: block-index constant pinned to int32 (a Python 0 traces as int64 under
+#: an x64 scope, which Mosaic cannot lower)
+_ZERO = np.int32(0)
+_pallas_minplus = None                  # lazily created jit
 
-    def kernel(a_ref, b_ref, o_ref):
-        vals = a_ref[...] + b_ref[...]      # (P, P) broadcast over rows
-        o_ref[...] = jnp.min(vals, axis=1, keepdims=True).T
 
-    P = A.shape[0]
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((1, P), A.dtype),
-        interpret=interpret,
-    )(A, b)
-    return np.asarray(out[0])
+def _get_pallas_minplus():
+    """jit: cur[u] = min_v A[u, v] + b[v] on padded (P, P)/(1, P) operands,
+    tiled over ``ROW_TILE``-row blocks of A."""
+    global _pallas_minplus
+    if _pallas_minplus is None:
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+
+        def kernel(a_ref, b_ref, o_ref):
+            vals = a_ref[...] + b_ref[...]      # (tile, P) broadcast over rows
+            o_ref[...] = jnp.min(vals, axis=1, keepdims=True).T
+
+        def impl(A, b, interpret):
+            P = A.shape[0]
+            return pl.pallas_call(
+                kernel,
+                grid=(P // ROW_TILE,),
+                in_specs=[pl.BlockSpec((ROW_TILE, P), lambda i: (i, _ZERO)),
+                          pl.BlockSpec((1, P), lambda i: (_ZERO, _ZERO))],
+                out_specs=pl.BlockSpec((1, ROW_TILE), lambda i: (_ZERO, i)),
+                out_shape=jax.ShapeDtypeStruct((1, P), A.dtype),
+                interpret=interpret,
+                name="minplus",
+            )(A, b)
+
+        _pallas_minplus = jax.jit(impl, static_argnames="interpret")
+    return _pallas_minplus
 
 
 def minplus_pallas(
@@ -136,45 +157,37 @@ def minplus_pallas(
 
     The Toeplitz operand is built host-side (O(Q^2), tiny); the kernel does
     the broadcast-add + min-reduce. Rows/cols are padded to the 128-lane
-    tile; padding is +inf-neutral (inf + inf = inf never wins a min)."""
-    global _pallas_broken
-    if _pallas_broken is not None:
-        return minplus_numpy(prev, tcost)
-    try:
-        import jax
-        import jax.numpy as jnp
+    tile; padding is +inf-neutral (inf + inf = inf never wins a min).
+    Raises ``ValueError`` when the padded width would exceed ``MAX_P``."""
+    import jax
 
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        Q1 = prev.size
-        P = max(128, int(np.ceil(Q1 / 128)) * 128)
-        big = np.float32(3.4e38 / 4)  # inf-surrogate safe under one add
-        idx = np.arange(Q1)
-        diff = idx[:, None] - idx[None, :]
-        A = np.full((P, P), big, dtype=np.float32)
-        A[:Q1, :Q1] = np.where(
-            diff >= 0, np.minimum(prev, big)[np.abs(diff)], big
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    Q1 = prev.size
+    P = max(ROW_TILE, int(np.ceil(Q1 / ROW_TILE)) * ROW_TILE)
+    if P > MAX_P:
+        raise ValueError(
+            f"minplus_pallas: {Q1} DP states pad to {P} > MAX_P={MAX_P}; "
+            "use the numpy min-plus backend for this quanta count"
         )
-        b = np.full((1, P), big, dtype=np.float32)
-        b[0, :Q1] = np.minimum(tcost, big)
-        cur32 = _pallas_minplus_call(jnp.asarray(A), jnp.asarray(b),
-                                     interpret)[:Q1]
-        best = np.where(cur32 >= big, _INF, cur32.astype(np.float64))
-        # backtracking pointers recovered host-side from the same operands
-        # (standard for DP kernels: the device computes values, not argmins)
-        vals32 = A[:Q1, :Q1] + b[0, :Q1][None, :]
-        choice = np.argmin(vals32, axis=1).astype(np.int64)
-        choice[~np.isfinite(best)] = -1
-        return best, choice
-    except Exception as e:  # missing jax, lowering failure, ...
-        _pallas_broken = f"{type(e).__name__}: {e}"
-        warn_once_event(
-            "repro_pallas_fallback_total", "minplus",
-            f"minplus Pallas path unavailable ({_pallas_broken}); "
-            "falling back to NumPy",
-            kernel="minplus", reason=_pallas_broken,
-        )
-        return minplus_numpy(prev, tcost)
+    big = np.float32(3.4e38 / 4)  # inf-surrogate safe under one add
+    idx = np.arange(Q1)
+    diff = idx[:, None] - idx[None, :]
+    A = np.full((P, P), big, dtype=np.float32)
+    A[:Q1, :Q1] = np.where(
+        diff >= 0, np.minimum(prev, big)[np.abs(diff)], big
+    )
+    b = np.full((1, P), big, dtype=np.float32)
+    b[0, :Q1] = np.minimum(tcost, big)
+    cur32 = np.asarray(
+        _get_pallas_minplus()(A, b, interpret=interpret)[0])[:Q1]
+    best = np.where(cur32 >= big, _INF, cur32.astype(np.float64))
+    # backtracking pointers recovered host-side from the same operands
+    # (standard for DP kernels: the device computes values, not argmins)
+    vals32 = A[:Q1, :Q1] + b[0, :Q1][None, :]
+    choice = np.argmin(vals32, axis=1).astype(np.int64)
+    choice[~np.isfinite(best)] = -1
+    return best, choice
 
 
 # --------------------------------------------------------------- dispatch
@@ -185,12 +198,8 @@ def default_backend() -> str:
     imports jax itself, so CPU-only probes stay jax-free."""
     import sys
     jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            if jax.default_backend() == "tpu":
-                return "pallas"
-        except Exception:
-            pass
+    if jax is not None and jax.default_backend() == "tpu":
+        return "pallas"
     return "numpy"
 
 
@@ -201,9 +210,8 @@ def minplus_step(
 
     None means NumPy *to this function*: the scheduler guarantees
     bit-identical decisions across hosts, so the float32 Pallas kernel
-    (whose own wrapper falls back to NumPy off-TPU) never self-selects
-    here. Callers opt in via SubproblemConfig(minplus_backend="pallas"),
-    or implicitly by running the jax *array* backend on an actual TPU
+    (interpret mode off-TPU) never self-selects here. Callers opt in via
+    SubproblemConfig(minplus_backend="pallas"), or implicitly by running the jax *array* backend on an actual TPU
     (WorkloadDP resolves a None config through
     ``ArrayBackend.minplus_default``) — the jax backend's contract is
     tolerance parity, not bit parity, so accelerator-dependent float32

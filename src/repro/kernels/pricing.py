@@ -17,18 +17,21 @@ min-reductions. Three implementations:
     per-resource accumulation order exactly (what the numpy backend's
     inline code computes);
   * ``price_bundle_jnp``    — one jit-compiled device pass; the jax
-    backend's default (float64 under the caller's ``enable_x64`` scope);
+    backend's default off-TPU (float64 under the caller's
+    ``jax.enable_x64(True)`` scope);
   * ``price_bundle_pallas`` — a Pallas TPU kernel for the three *price*
-    reductions as one (8, Rp) x (Hp, Rp) ``dot_general`` contraction on
-    the MXU, padded to the float32 tile grid with zero-neutral padding.
-    Off-TPU it runs in interpret mode; any import/lowering failure falls
-    back to the jnp path (the ``minplus``/``rmsnorm`` kernel pattern).
+    reductions as an (8, Rp) x (tile, Rp) ``dot_general`` contraction on
+    the MXU per grid step, over ``ROW_TILE``-row tiles of the operand
+    padded to the float32 tile grid with zero-neutral padding. Off-TPU it
+    runs in interpret mode. A lowering or compile failure raises: no
+    path falls back to another implementation.
 
 The Pallas path's price rows are float32 (like ``kernels/minplus.py``):
-tolerance-tested against the references, auto-selected only on an actual
-TPU, and forceable via ``REPRO_PRICE_KERNEL=pallas`` for interpret-mode
-testing. The head-room rows are NEVER float32 on any path: ``max_w`` /
-``max_s`` are integer-valued decisions (a float32 reciprocal-multiply can
+tolerance-tested against the references, auto-selected on a TPU, and
+forceable via ``REPRO_PRICE_KERNEL=pallas`` for interpret-mode testing
+(``REPRO_PRICE_KERNEL=jnp`` forces the float64 jnp pass on a TPU). The
+head-room rows are NEVER float32 on any path: ``max_w`` / ``max_s`` are
+integer-valued decisions (a float32 reciprocal-multiply can
 overestimate them by a whole unit at exact-capacity boundaries, e.g.
 free=8.9999999/demand=3 rounding up through floor), so the Pallas wrapper
 computes them host-side in float64 with exactly the reference arithmetic.
@@ -42,12 +45,9 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from ..obs.metrics import warn_once_event
-
-_pallas_broken: Optional[str] = None   # first failure reason, warn once
 _jnp_bundle = None                     # lazily created jit
 _jnp_bundle_batch = None               # lazily created jit (fused multi-slot)
-TRACE_COUNTS = {"bundle_jnp": 0, "bundle_batch_jnp": 0}
+TRACE_COUNTS = {"bundle_jnp": 0, "bundle_batch_jnp": 0, "bundle_pallas": 0}
 
 Bundle = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]
 
@@ -124,91 +124,124 @@ def price_bundle_jnp(price, free, wdem: np.ndarray, sdem: np.ndarray,
 
 
 # ---------------------------------------------------------------- pallas
-def _pallas_bundle_call(P, W, interpret: bool):
-    """red = W (dot) P^T on padded operands.
+#: rows of the price operand per grid step: a (4096, 128) f32 tile is
+#: 2 MiB, so the double-buffered input stays far inside the default
+#: scoped VMEM at any row count the plan can pass
+ROW_TILE = 4096
+_LANES = 128
+#: block-index constant pinned to int32: a Python 0 traces as int64 under
+#: the caller's x64 scope, which Mosaic cannot lower
+_ZERO = np.int32(0)
+_pallas_bundle = None                  # lazily created jit
 
-    P: (Hp, Rp) price matrix; W: (8, Rp) weight rows (0: alpha, 1: beta,
-    2: alpha*gamma+beta, 3..7: zero). Output (8, Hp): rows 0-2 the three
-    masked price reductions."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    def kernel(p_ref, w_ref, o_ref):
-        o_ref[...] = jax.lax.dot_general(
-            w_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                              # (8, Hp)
+def _round_up(n: int, m: int) -> int:
+    return max(m, -(-n // m) * m)
 
-    Hp = P.shape[0]
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((8, Hp), jnp.float32),
-        interpret=interpret,
-    )(P, W)
-    return np.asarray(out)
+
+def _get_pallas_bundle():
+    """jit: (W, H, R) prices + (8, Rp) weights -> (3, W*H) f32 reductions.
+
+    The f32 cast and the zero padding to the (row tile, 128-lane) grid run
+    on the device, so a device-resident price tensor never visits the
+    host. Zero padding is reduction-neutral: padded weight and price
+    columns add nothing to a dot row, and padded rows are sliced off."""
+    global _pallas_bundle
+    if _pallas_bundle is None:
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+
+        def kernel(p_ref, w_ref, o_ref):
+            # HIGHEST: the MXU's default f32 precision rounds operands to
+            # bf16 (relative error ~4e-3 on the chip); full-f32 passes
+            # keep the reductions within float32 rounding
+            o_ref[...] = jax.lax.dot_general(
+                w_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )                                          # (8, tile)
+
+        def impl(price, wmat, interpret):
+            TRACE_COUNTS["bundle_pallas"] += 1
+            rows = price.shape[0] * price.shape[1]
+            R = price.shape[2]
+            Rp = wmat.shape[1]
+            tile = min(ROW_TILE, _round_up(rows, _LANES))
+            Hp = _round_up(rows, tile)
+            P = jnp.pad(price.reshape(rows, R).astype(jnp.float32),
+                        ((0, Hp - rows), (0, Rp - R)))
+            out = pl.pallas_call(
+                kernel,
+                grid=(Hp // tile,),
+                in_specs=[pl.BlockSpec((tile, Rp), lambda i: (i, _ZERO)),
+                          pl.BlockSpec((8, Rp), lambda i: (_ZERO, _ZERO))],
+                out_specs=pl.BlockSpec((8, tile), lambda i: (_ZERO, i)),
+                out_shape=jax.ShapeDtypeStruct((8, Hp), jnp.float32),
+                interpret=interpret,
+                name="price_bundle",
+            )(P, wmat)
+            return out[:3, :rows]
+
+        _pallas_bundle = jax.jit(impl, static_argnames="interpret")
+    return _pallas_bundle
+
+
+def bundle_weights(wdem: np.ndarray, sdem: np.ndarray,
+                   gamma: float) -> np.ndarray:
+    """(8, Rp) f32 weight rows: alpha, beta, alpha*gamma+beta, then zero."""
+    R = wdem.shape[0]
+    wmat = np.zeros((8, _round_up(R, _LANES)), dtype=np.float32)
+    wmat[0, :R] = wdem
+    wmat[1, :R] = sdem
+    wmat[2, :R] = wdem * gamma + sdem
+    return wmat
 
 
 def _headroom_exact(free64: np.ndarray, dem: np.ndarray) -> np.ndarray:
     """floor(min over demand-positive resources of free/dem) in float64 —
-    the reference arithmetic; integer-valued, so never float32."""
+    the reference arithmetic over the last axis; integer-valued, so never
+    float32."""
     pos = dem > 0
     if not pos.any():
-        return np.full(free64.shape[0], np.inf)
-    ratio = (free64[:, pos] / dem[pos][None, :]).min(axis=1)
+        return np.full(free64.shape[:-1], np.inf)
+    ratio = (free64[..., pos] / dem[pos]).min(axis=-1)
     return np.floor(np.maximum(ratio, 0.0))
+
+
+def price_bundle_batch_pallas(price, free, wdem: np.ndarray,
+                              sdem: np.ndarray, gamma: float,
+                              interpret: Optional[bool] = None) -> Bundle:
+    """Pallas TPU kernel for the fused batch (float32 prices).
+
+    The (W, H, R) price stack is flattened to one (W*H, R) operand and
+    reduced by a row-tiled MXU ``dot_general`` — one kernel launch for
+    every slot of the plan. The head-room rows are computed host-side in
+    float64 (see the module docstring: a float32 ratio can overestimate
+    the integer head-room by a whole unit at exact-capacity boundaries,
+    which would let the snapshot advertise a worker that does not fit)."""
+    import jax
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    free64 = np.asarray(free, dtype=np.float64)
+    wdem = np.asarray(wdem, dtype=np.float64)
+    sdem = np.asarray(sdem, dtype=np.float64)
+    W, H = free64.shape[0], free64.shape[1]
+    red = _get_pallas_bundle()(price, bundle_weights(wdem, sdem, gamma),
+                               interpret=interpret)
+    out = np.asarray(red, dtype=np.float64).reshape(3, W, H)
+    return (out[0], out[1], out[2],
+            _headroom_exact(free64, wdem), _headroom_exact(free64, sdem))
 
 
 def price_bundle_pallas(price, free, wdem: np.ndarray, sdem: np.ndarray,
                         gamma: float,
                         interpret: Optional[bool] = None) -> Bundle:
-    """Pallas TPU kernel for the masked price reduction (float32 prices).
-
-    Padding is reduction-neutral: zero weight/price columns add nothing
-    to the dot rows, and machines beyond H are sliced off host-side. The
-    head-room rows are computed host-side in float64 (see the module
-    docstring: a float32 ratio can overestimate the integer head-room by
-    a whole unit at exact-capacity boundaries, which would let the
-    snapshot advertise a worker that does not fit)."""
-    global _pallas_broken
-    free64 = np.asarray(free, dtype=np.float64)
-    wdem = np.asarray(wdem, dtype=np.float64)
-    sdem = np.asarray(sdem, dtype=np.float64)
-    max_w = _headroom_exact(free64, wdem)
-    max_s = _headroom_exact(free64, sdem)
-    if _pallas_broken is not None:
-        out = price_bundle_jnp(price, free, wdem, sdem, gamma)
-        return out[0], out[1], out[2], max_w, max_s
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        price = np.asarray(price, dtype=np.float32)
-        H, R = price.shape
-        Hp = max(128, int(np.ceil(H / 128)) * 128)
-        Rp = max(128, int(np.ceil(R / 128)) * 128)
-        P = np.zeros((Hp, Rp), dtype=np.float32)
-        P[:H, :R] = price
-        W = np.zeros((8, Rp), dtype=np.float32)
-        W[0, :R] = wdem.astype(np.float32)
-        W[1, :R] = sdem.astype(np.float32)
-        W[2, :R] = (wdem * gamma + sdem).astype(np.float32)
-        out = _pallas_bundle_call(
-            jnp.asarray(P), jnp.asarray(W), interpret
-        )[:, :H].astype(np.float64)
-        return out[0], out[1], out[2], max_w, max_s
-    except Exception as e:  # missing jax, lowering failure, ...
-        _pallas_broken = f"{type(e).__name__}: {e}"
-        warn_once_event(
-            "repro_pallas_fallback_total", "pricing.bundle",
-            f"pricing Pallas path unavailable ({_pallas_broken}); "
-            "falling back to jnp",
-            kernel="pricing.bundle", reason=_pallas_broken,
-        )
-        out = price_bundle_jnp(price, free, wdem, sdem, gamma)
-        return out[0], out[1], out[2], max_w, max_s
+    """One slot's (H, R) reduction through the batch kernel (W = 1)."""
+    out = price_bundle_batch_pallas(price[None], np.asarray(free)[None],
+                                    wdem, sdem, gamma, interpret=interpret)
+    return tuple(o[0] for o in out)
 
 
 # ------------------------------------------------- fused multi-slot batch
@@ -282,66 +315,6 @@ def price_bundle_batch_jnp(price, free, wdem: np.ndarray, sdem: np.ndarray,
     out = fn(price, free, np.asarray(wdem, dtype=np.float64),
              np.asarray(sdem, dtype=np.float64), float(gamma))
     return tuple(np.asarray(o, dtype=np.float64) for o in out)
-
-
-def price_bundle_batch_pallas(price, free, wdem: np.ndarray,
-                              sdem: np.ndarray, gamma: float,
-                              interpret: Optional[bool] = None) -> Bundle:
-    """Pallas TPU path for the fused batch: the (W, H, R) price stack is
-    flattened to one (W*H, R) operand and pushed through the same padded
-    MXU ``dot_general`` kernel as the per-slot path — one kernel launch
-    for every slot of the plan. Head-room rows stay host-side float64
-    (integer-valued decisions; see the module docstring). Falls back to
-    the jnp batch pass on any kernel failure."""
-    global _pallas_broken
-    free64 = np.asarray(free, dtype=np.float64)
-    wdem = np.asarray(wdem, dtype=np.float64)
-    sdem = np.asarray(sdem, dtype=np.float64)
-    # .shape reads need no host transfer (device or host array alike)
-    W, H, R = price.shape[0], free64.shape[1], free64.shape[2]
-
-    def headroom(dem):
-        pos = dem > 0
-        if not pos.any():
-            return np.full((W, H), np.inf)
-        ratio = (free64[:, :, pos] / dem[pos][None, None, :]).min(axis=2)
-        return np.floor(np.maximum(ratio, 0.0))
-
-    max_w = headroom(wdem)
-    max_s = headroom(sdem)
-    if _pallas_broken is not None:
-        out = price_bundle_batch_jnp(price, free, wdem, sdem, gamma)
-        return out[0], out[1], out[2], max_w, max_s
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        flat = np.asarray(price, dtype=np.float32).reshape(W * H, R)
-        WH = W * H
-        Hp = max(128, int(np.ceil(WH / 128)) * 128)
-        Rp = max(128, int(np.ceil(R / 128)) * 128)
-        P = np.zeros((Hp, Rp), dtype=np.float32)
-        P[:WH, :R] = flat
-        Wm = np.zeros((8, Rp), dtype=np.float32)
-        Wm[0, :R] = wdem.astype(np.float32)
-        Wm[1, :R] = sdem.astype(np.float32)
-        Wm[2, :R] = (wdem * gamma + sdem).astype(np.float32)
-        out = _pallas_bundle_call(
-            jnp.asarray(P), jnp.asarray(Wm), interpret
-        )[:3, :WH].astype(np.float64).reshape(3, W, H)
-        return out[0], out[1], out[2], max_w, max_s
-    except Exception as e:  # missing jax, lowering failure, ...
-        _pallas_broken = f"{type(e).__name__}: {e}"
-        warn_once_event(
-            "repro_pallas_fallback_total", "pricing.bundle_batch",
-            f"pricing Pallas batch path unavailable ({_pallas_broken}); "
-            "falling back to jnp",
-            kernel="pricing.bundle_batch", reason=_pallas_broken,
-        )
-        out = price_bundle_batch_jnp(price, free, wdem, sdem, gamma)
-        return out[0], out[1], out[2], max_w, max_s
 
 
 def price_bundle_batch(price, free, wdem: np.ndarray, sdem: np.ndarray,

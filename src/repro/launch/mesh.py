@@ -3,6 +3,7 @@ jax device state; the dry-run sets XLA_FLAGS before any jax init)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False, model_split: int = 0):
@@ -20,14 +21,16 @@ def make_production_mesh(*, multi_pod: bool = False, model_split: int = 0):
         else:
             shape = (16, 16 // model_split, model_split)
             axes = ("data", "model", "model2")
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU)."""
     n = len(jax.devices())
     assert data * model <= n, f"need {data * model} devices, have {n}"
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -2,10 +2,9 @@
 
 Promoted from the ad-hoc fuzz loops in ``tests/test_sim_batch.py`` so the
 batched-equivalence suite and the elastic/reshape suite (ISSUE 10) draw
-their traces, fault plans, and reshape storms from ONE place. Everything
-here works under the real ``hypothesis`` library *and* the deterministic
-conftest fallback stub (only ``integers``/``floats``/``sampled_from`` are
-used).
+their traces, fault plans, and reshape storms from ONE place. The
+per-example deadline is off for every test (the ``tests/conftest.py``
+Hypothesis profile): one example here is a whole engine run.
 
 Building blocks
 ---------------

@@ -99,7 +99,7 @@ def test_ledger_ops_parity():
     assert un.shape == uj.shape
     np.testing.assert_allclose(uj, un, rtol=0, atol=1e-12)
     assert (un >= 0).all() and (uj >= 0).all()
-    # ledger dtype stays float64 on device (enable_x64-scoped ops)
+    # ledger dtype stays float64 on device (jax.enable_x64-scoped ops)
     assert clj._used.dtype == np.float64
 
 
@@ -163,8 +163,6 @@ def test_free_matrix_parity_after_mutations():
 
 # ---------------------------------------------------------- bundle kernels
 def test_price_bundle_kernels_agree():
-    from jax.experimental import enable_x64
-
     from repro.kernels.pricing import (
         price_bundle_jnp,
         price_bundle_numpy,
@@ -179,7 +177,7 @@ def test_price_bundle_kernels_agree():
         sdem = rng.uniform(0.0, 3.0, R) * (rng.random(R) > 0.3)
         gamma = 4.0
         ref = price_bundle_numpy(price, free, wdem, sdem, gamma)
-        with enable_x64():
+        with jax.enable_x64(True):
             jn = price_bundle_jnp(price, free, wdem, sdem, gamma)
         pl = price_bundle_pallas(price, free, wdem, sdem, gamma)
         for a, b in zip(ref, jn):

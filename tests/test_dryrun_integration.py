@@ -19,7 +19,8 @@ from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.launch.dryrun import dryrun_one
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 out = []
 cases = [
     ("qwen3-32b", InputShape("t", 256, 8, "train")),
@@ -63,7 +64,8 @@ def test_mesh_rules_divisibility_fallback():
     import jax
     from repro.parallel.sharding import MeshRules
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = MeshRules(mesh)
     # model axis size 1 divides everything; use spec_for paths directly
     spec = rules.spec_for("layers/attn/wk", (64, 1024, 8, 128))

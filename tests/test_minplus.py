@@ -8,6 +8,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.minplus import (
+    MAX_P,
     default_backend,
     minplus_numpy,
     minplus_pallas,
@@ -70,6 +71,14 @@ def test_property_pallas_matches_scalar(seed):
         assert prev[u - v] + tcost[v] == pytest.approx(cs[u], rel=2e-6, abs=2e-4)
 
 
+def test_pallas_rejects_width_above_max():
+    """Above MAX_P padded states the kernel would overflow VMEM: the
+    wrapper refuses with a clear error instead of a compiler failure."""
+    n = MAX_P + 1
+    with pytest.raises(ValueError, match="MAX_P"):
+        minplus_pallas(np.zeros(n), np.zeros(n), interpret=True)
+
+
 def test_all_unreachable():
     prev = np.full(5, np.inf)
     tcost = np.zeros(5)
@@ -96,7 +105,7 @@ def test_dispatch_and_fallback():
     for backend in (None, "numpy", "scalar"):
         cur, ch = minplus_step(prev, tcost, backend=backend)
         np.testing.assert_allclose(cur, [0.0, 1.0, 2.0])
-    # pallas path must return (via kernel or clean numpy fallback) off-TPU
+    # the pallas path runs the kernel in interpret mode off-TPU
     cur, ch = minplus_step(prev, tcost, backend="pallas")
     np.testing.assert_allclose(cur, [0.0, 1.0, 2.0], rtol=1e-6)
 
