@@ -1,0 +1,124 @@
+"""The reference must catch a broken timed path: a CPU run of the whole
+harness after the device check (``run.execute``) at a size a test can
+hold, once sound and once with each fault of ``planted.py`` planted
+underneath, must come out ``correct`` and not ``correct`` respectively.
+``stale_prices`` is the control. A one-chip cell has no exchange between
+chips, so that fault has no case here."""
+import importlib.util
+import json
+import sys
+import time
+from pathlib import Path
+
+import pytest
+
+BENCH_DIR = Path(__file__).resolve().parent
+REPO = BENCH_DIR.parents[1]
+sys.path.insert(0, str(BENCH_DIR))
+sys.path.insert(0, str(REPO / "src"))
+
+import planted  # noqa: E402
+from gen.traffic import Traffic  # noqa: E402
+from harness import engine as eng  # noqa: E402
+from harness.cells import Config  # noqa: E402
+
+_spec = importlib.util.spec_from_file_location("chipbench_run", BENCH_DIR / "run.py")
+bench_run = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(bench_run)
+
+CFG = Config("tiny", 24, 12, 8, "ethernet",
+             {"cpu": 180.0, "gpu": 72.0, "mem": 576.0, "storage": 180.0})
+TRAFFIC = Traffic(preset="google", arrival_rate=6.0, workload_scale=0.02,
+                  failure_rate=0.1, warm_slots=6)
+BENCH = {"end_to_end": [{"name": n, "unit": "u"} for n in
+                        ("jobs_per_s", "decide_p50_ms", "decide_p90_ms",
+                         "setup_s")], "per_layer": []}
+CELL = {"name": "tiny.cell", "chips": 1}
+LIMITS = json.loads((BENCH_DIR / "limits" / "google1024.light.json").read_text())
+SEED = 2**31 + 17
+
+
+def _execute(monkeypatch, fault=None, backend="numpy", close_slot=16,
+             trace=False, bench=BENCH, cell=CELL):
+    """One whole run on the CPU, its window closed at ``close_slot``, with
+    ``fault`` planted in the built run."""
+    build, undo = eng.build, []
+
+    def planted_build(*a, **kw):
+        run = build(*a, **kw)
+        run.recorder.close_slot = close_slot
+        if fault is not None:
+            undo.append(fault(run))
+        return run
+
+    monkeypatch.setattr(eng, "build", planted_build)
+    try:
+        return bench_run.execute(bench, cell, CFG, TRAFFIC, LIMITS, SEED, 1e9,
+                                 trace, backend=backend,
+                                 t_start=time.perf_counter())
+    finally:
+        for u in undo:
+            u()
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_sound_run_is_correct(monkeypatch, backend):
+    res, nums = _execute(monkeypatch, backend=backend)
+    assert res["correct"], res["checks"]
+    assert nums.offers >= 30 and nums.offers == res["attempted"]
+    assert list(res)[-1] == "checks"
+
+
+# the jax backend (on the CPU here) clamps a release silently, as on the
+# chip; the numpy backend would assert before the reference could look
+@pytest.mark.parametrize("fault, number, backend", [
+    (planted.state_unchanged, "ledger_gap", "jax"),
+    (planted.half_batch, "unanswered", "numpy"),
+    (planted.answer_altered, "invalid", "numpy"),
+    (planted.answer_rejected, "payoff_gap", "numpy"),
+    (planted.stale_prices, "payoff_gap", "numpy"),
+    (planted.no_splits, "payoff_gap", "numpy"),
+])
+def test_planted_fault_is_not_correct(monkeypatch, fault, number, backend):
+    res, _ = _execute(monkeypatch, fault=fault, backend=backend)
+    assert res["correct"] is False
+    got = res["checks"][number]
+    assert got["value"] > got["limit"]
+
+
+def test_no_splits_puts_the_program_back():
+    from repro.core import solve_plan, subproblem
+    before = (solve_plan._prune_fill, subproblem._prune_stats)
+    planted.no_splits(None)()
+    assert (solve_plan._prune_fill, subproblem._prune_stats) == before
+
+
+def test_traced_run_reads_the_per_layer_metrics(monkeypatch):
+    """The ``--trace 1`` path end to end on the CPU: the profiler trace is
+    reduced (the CPU's op line stands in for the TPU's), every reader
+    runs on a real context, and the result carries busy and window
+    seconds and a breakdown."""
+    import functools
+    from harness import trace, work
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cell = dict(bench["workloads"][0])
+    monkeypatch.setattr(trace, "load", functools.partial(
+        trace.load, device_prefix="/host:CPU",
+        op_line=lambda n: n.startswith("tf_XLAPjRtCpuClient")))
+    v5e = work.peaks("TPU v5 lite")
+    monkeypatch.setattr(work, "peaks", lambda kind: v5e)
+    res, _ = _execute(monkeypatch, backend="jax", close_slot=12, trace=True,
+                      bench=bench, cell=cell)
+    assert res["correct"], res["checks"]
+    dev = res["device"]
+    assert 0 < dev["busy_s"] < dev["window_s"]
+    got = res["metrics"]
+    for name in ("engine.ms_per_slot", "offer.plan_ms_per_job",
+                 "dp.ms_per_job", "price.ms_per_job", "device.idle_share",
+                 "device.compiles"):
+        assert name in got, name
+    assert 0 < got["device.idle_share"]["value"] < 100
+    # no Pallas kernel runs off a TPU, so their rooflines stay silent
+    assert "price_bundle_roofline" not in got and "minplus_roofline" not in got
+    assert res["breakdown"]["device_ops"] and res["breakdown"]["idle_gaps"]
+    assert list(res)[-1] == "checks"

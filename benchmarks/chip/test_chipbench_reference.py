@@ -1,0 +1,67 @@
+"""The plain reference's search, by hand: the co-located and the split
+placement of each workload level, and the DP over slots (CPU only)."""
+import math
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+BENCH_DIR = Path(__file__).resolve().parent
+sys.path.insert(0, str(BENCH_DIR))
+
+from gen.jobmath import PlainJob  # noqa: E402
+from harness import reference  # noqa: E402
+
+RES = ["cpu", "mem"]
+# one sample a worker a slot, V = 4 samples in Q = 4 levels, at most 4
+# workers, a server for every two; a worker takes 10 cpu, a server 5
+JOB = PlainJob(job_id=1, arrival=0, epochs=1, num_samples=4, batch_size=4,
+               tau=1.0, grad_size=0.0, gamma=2.0, bw_internal=1.0,
+               bw_external=1.0, worker_demand=(("cpu", 10.0), ("mem", 0.0)),
+               ps_demand=(("cpu", 5.0), ("mem", 0.0)),
+               theta=(1000.0, 1.0, 0.5))
+
+
+def _cluster(slots):
+    """Three empty machines of 25 cpu; machine h costs h + 1 a cpu."""
+    free = np.tile(np.array([25.0, 100.0]), (slots, 3, 1))
+    price = np.ones((slots, 3, 2))
+    price[:, :, 0] = [1.0, 2.0, 3.0]
+    return free, price
+
+
+def test_level_costs_by_hand():
+    free, price = _cluster(1)
+    coloc, split = reference.level_costs(JOB, free, price, RES, 4)
+    # co-located on machine 0: v workers and ceil(v / 2) servers while
+    # 10 v + 5 ceil(v / 2) <= 25
+    assert coloc[0].tolist() == [0.0, 15.0, 25.0, math.inf, math.inf]
+    # split: two workers a machine, cheapest first; servers on the
+    # cheapest machines that hold no worker
+    assert split[0].tolist() == [0.0, 20.0, 30.0, 70.0, 90.0]
+
+
+def test_level_costs_respect_what_is_used():
+    free, price = _cluster(1)
+    free[0, 0, 0] = 4.0                    # machine 0 holds no worker
+    coloc, split = reference.level_costs(JOB, free, price, RES, 4)
+    assert coloc[0].tolist() == [0.0, 30.0, 50.0, math.inf, math.inf]
+    # workers on 1 (and 2), servers on what is left: machine 0 fits none
+    # of 5 cpu, so v = 1 puts its server on machine 2
+    assert split[0].tolist() == [0.0, 35.0, 55.0, math.inf, math.inf]
+
+
+def test_best_schedule_by_hand():
+    free, price = _cluster(2)
+    coloc, split = reference.level_costs(JOB, free, price, RES, 4)
+    u0 = 1000.0 / (1.0 + math.exp(-0.5))
+    u1 = 1000.0 / (1.0 + math.exp(0.5))
+    full = reference.best_schedule(JOB, np.minimum(coloc, split))
+    # finishing in slot 0 takes the split placement of all 4 levels (90)
+    assert full.payoff == pytest.approx(u0 - 90.0)
+    assert full.cost == pytest.approx(90.0)
+    alone = reference.best_schedule(JOB, coloc)
+    # co-located alone needs both slots: 2 levels each at 25
+    assert alone.payoff == pytest.approx(u1 - 50.0)
+    assert reference.best_schedule(JOB, coloc[:1]) is None
