@@ -79,8 +79,8 @@ PUBLIC_SYMBOLS = {
                                  "metrics_text", "start_http"],
     "src/repro/backend/__init__.py": ["lp_solver_default"],
     "benchmarks/bench_scheduler.py": ["repeat-best-of", "--profile"],
-    "src/repro/obs/trace.py": ["Tracer", "Span", "chrome_trace",
-                               "phase_table", "total_self_s", "activate"],
+    "src/repro/obs/trace.py": ["Tracer", "Span", "phase_table",
+                               "total_self_s", "activate", "device_get"],
     "src/repro/obs/metrics.py": ["MetricsRegistry", "Counter", "Gauge",
                                  "Histogram", "get_registry",
                                  "warn_once_event", "render", "snapshot"],

@@ -64,9 +64,10 @@ class ArrayBackend:
         """A fresh all-zero ledger array of the backend's native type."""
         raise NotImplementedError
 
-    def to_host(self, arr) -> np.ndarray:
+    def to_host(self, arr, site: str = "to_host") -> np.ndarray:
         """The array as a host ``np.ndarray`` (no-op for numpy; a device
-        sync for jax — call only at the documented sync points)."""
+        sync for jax — call only at the documented sync points). ``site``
+        names the read in the jax backend's ``device.sync`` span."""
         raise NotImplementedError
 
     # ---- ledger mutations (Algorithm 1 step 3 and its inverses) ---------

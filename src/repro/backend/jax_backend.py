@@ -22,6 +22,12 @@ slow, test-only). The release clamp never asserts on this backend (the
 assert would force a device sync per release); the clamp itself is
 preserved, and the invariant is covered by the parity tests.
 
+Every op that enqueues device work runs under a ``device.launch`` span
+and every blocking read under a ``device.sync`` span (``repro.obs.trace``),
+each named by its ``site``; every jitted function carries a stable name
+(``jit_ledger_scatter_add`` and so on, with a ``jax.named_scope`` of the
+same name over its body) so that its ops can be told apart in a profile.
+
 Building the backend on a TPU configures JAX's persistent compilation cache
 (``configure_compile_cache``): ``JAX_COMPILATION_CACHE_DIR`` when set,
 else the fixed ``<checkout>/.jax_cache``, keeping even sub-second jits.
@@ -36,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace as _trace
 from . import ArrayBackend
 
 #: default persistent compile cache: a fixed path inside the checkout (the
@@ -71,43 +78,59 @@ class JaxBackend(ArrayBackend):
             "free_tensor": 0, "price_tensor": 0,
         }
 
-        def _free_impl(used, cap):
+        def free_tensor(used, cap):
             self.trace_counts["free_tensor"] += 1
-            return cap[None, :, :] - used
+            with jax.named_scope("free_tensor"):
+                return cap[None, :, :] - used
 
-        def _price_impl(used, cap, u, L):
+        def price_tensor(used, cap, u, L):
             self.trace_counts["price_tensor"] += 1
-            capb = cap[None, :, :]
-            pos = capb > 0
-            frac = jnp.where(pos, used / jnp.where(pos, capb, 1.0), 0.0)
-            frac = jnp.clip(frac, 0.0, 1.0)
-            ub = u[None, None, :]
-            out = L * (ub / L) ** frac
-            return jnp.where(pos, out, ub)
-
-        self._free_jit = jax.jit(_free_impl)
-        self._price_jit = jax.jit(_price_impl)
+            with jax.named_scope("price_tensor"):
+                capb = cap[None, :, :]
+                pos = capb > 0
+                frac = jnp.where(pos, used / jnp.where(pos, capb, 1.0), 0.0)
+                frac = jnp.clip(frac, 0.0, 1.0)
+                ub = u[None, None, :]
+                out = L * (ub / L) ** frac
+                return jnp.where(pos, out, ub)
 
         # jitted ledger scatters with the slot index as a TRACED scalar:
         # a python-int `t` would be baked into the jaxpr as a constant,
         # recompiling per (slot, width) pair instead of per width only
-        def _scatter_add(used, t, hs, vecs):
-            return used.at[t, hs].add(vecs)
+        def ledger_scatter_add(used, t, hs, vecs):
+            with jax.named_scope("ledger_scatter_add"):
+                return used.at[t, hs].add(vecs)
 
-        def _scatter_sub_clamped(used, t, hs, vecs):
-            rows = jnp.maximum(used[t, hs] - vecs, 0.0)
-            return used.at[t, hs].set(rows)
+        def ledger_scatter_sub_clamped(used, t, hs, vecs):
+            with jax.named_scope("ledger_scatter_sub_clamped"):
+                rows = jnp.maximum(used[t, hs] - vecs, 0.0)
+                return used.at[t, hs].set(rows)
 
-        self._scatter_add = jax.jit(_scatter_add)
-        self._scatter_sub = jax.jit(_scatter_sub_clamped)
+        # the shift k (0 <= k <= T) is traced too: one compile serves
+        # every step count; rows shifted in from the zero half are zero
+        def ledger_advance(used, k):
+            with jax.named_scope("ledger_advance"):
+                padded = jnp.concatenate([used, jnp.zeros_like(used)])
+                return jax.lax.dynamic_slice_in_dim(padded, k, used.shape[0])
+
+        def oversubscribed(used, cap, tol):
+            with jax.named_scope("oversubscribed"):
+                return ((used - cap[None, :, :]) > tol).any()
+
+        self._free_jit = jax.jit(free_tensor)
+        self._price_jit = jax.jit(price_tensor)
+        self._scatter_add = jax.jit(ledger_scatter_add)
+        self._scatter_sub = jax.jit(ledger_scatter_sub_clamped)
+        self._advance_jit = jax.jit(ledger_advance)
+        self._over_jit = jax.jit(oversubscribed)
 
     # ---- array lifecycle ------------------------------------------------
     def zeros(self, shape):
-        with jax.enable_x64(True):
+        with _trace.launch("zeros"), jax.enable_x64(True):
             return jnp.zeros(shape, dtype=jnp.float64)
 
-    def to_host(self, arr) -> np.ndarray:
-        return np.asarray(arr)
+    def to_host(self, arr, site: str = "to_host") -> np.ndarray:
+        return _trace.device_get(arr, site)
 
     # ---- ledger mutations ----------------------------------------------
     @staticmethod
@@ -147,7 +170,7 @@ class JaxBackend(ArrayBackend):
         hs = np.array([h for h, _ in needs], dtype=np.int64)
         vecs = np.stack([need for _, need in needs])
         hs, vecs = self._pad_scatter(hs, vecs, neutral_vec=True)
-        with jax.enable_x64(True):
+        with _trace.launch("ledger_add"), jax.enable_x64(True):
             return self._scatter_add(used, np.int64(t), hs,
                                      jnp.asarray(vecs))
 
@@ -161,32 +184,29 @@ class JaxBackend(ArrayBackend):
         hs = np.array([h for h, _ in needs], dtype=np.int64)
         vecs = np.stack([need for _, need in needs])
         hs, vecs = self._pad_scatter(hs, vecs, neutral_vec=False)
-        with jax.enable_x64(True):
+        with _trace.launch("ledger_sub_clamped"), jax.enable_x64(True):
             return self._scatter_sub(used, np.int64(t), hs,
                                      jnp.asarray(vecs))
 
     def ledger_advance(self, used, steps: int):
-        with jax.enable_x64(True):
-            T = used.shape[0]
-            k = min(steps, T)
-            if k >= T:
-                return jnp.zeros_like(used)
-            pad = jnp.zeros((k,) + used.shape[1:], dtype=used.dtype)
-            return jnp.concatenate([used[k:], pad], axis=0)
+        k = min(steps, used.shape[0])
+        with _trace.launch("ledger_advance"), jax.enable_x64(True):
+            return self._advance_jit(used, np.int64(k))
 
     # ---- derived tensors ------------------------------------------------
     def free_tensor(self, used, cap: np.ndarray):
-        with jax.enable_x64(True):
+        with _trace.launch("free_tensor"), jax.enable_x64(True):
             return self._free_jit(used, cap)
 
     def price_tensor(self, used, cap: np.ndarray, u: np.ndarray, L: float):
-        with jax.enable_x64(True):
+        with _trace.launch("price_tensor"), jax.enable_x64(True):
             return self._price_jit(used, cap, u, np.float64(L))
 
     def oversubscribed(self, used, cap: np.ndarray, tol: float) -> bool:
-        with jax.enable_x64(True):
-            over = used - jnp.asarray(cap)[None, :, :]
-            return bool((over > tol).any())
+        with _trace.launch("oversubscribed"), jax.enable_x64(True):
+            over = self._over_jit(used, cap, np.float64(tol))
+        with _trace.sync("oversubscribed"):
+            return bool(over)
 
     @staticmethod
     def _price_kernel() -> Optional[str]:

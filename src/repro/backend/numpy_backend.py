@@ -22,7 +22,7 @@ class NumpyBackend(ArrayBackend):
     def zeros(self, shape) -> np.ndarray:
         return np.zeros(shape)
 
-    def to_host(self, arr) -> np.ndarray:
+    def to_host(self, arr, site: str = "to_host") -> np.ndarray:
         return np.asarray(arr)
 
     # ---- ledger mutations ----------------------------------------------

@@ -142,7 +142,8 @@ class Cluster:
         if self.backend.is_device:
             ent = self._used_host
             if ent is None or ent[0] != self.version:
-                ent = (self.version, self.backend.to_host(self._used))
+                ent = (self.version,
+                       self.backend.to_host(self._used, "to_host:used"))
                 self._used_host = ent
             return ent[1][t]
         return self._used[t]
@@ -163,7 +164,8 @@ class Cluster:
         sync per ledger version that serves every slot's free_matrix."""
         ent = self._free_host
         if ent is None or ent[0] != self.version:
-            ent = (self.version, self.backend.to_host(self.device_free_tensor()))
+            ent = (self.version, self.backend.to_host(
+                self.device_free_tensor(), "to_host:free"))
             self._free_host = ent
         return ent[1]
 
@@ -235,7 +237,7 @@ class Cluster:
         rows. Cold path: one host read of the machine's (T, R) ledger
         column per call."""
         if self.backend.is_device:
-            used = self.backend.to_host(self._used)[:, h, :]
+            used = self.backend.to_host(self._used, "to_host:used")[:, h, :]
         else:
             used = self._used[:, h, :]
         return bool(np.any(used > self.capacity_matrix[h][None, :] + tol))

@@ -219,7 +219,8 @@ class PriceTable:
                 "repro_price_prewarm_total",
                 "full (T,H,R) price-tensor rebuilds").inc()
             if cl.backend.is_device:
-                mats = cl.backend.to_host(self.device_tensor())
+                mats = cl.backend.to_host(self.device_tensor(),
+                                          "to_host:price")
                 for t in range(cl.horizon):
                     self._matrix_cache[t] = (version, mats[t])
                 return

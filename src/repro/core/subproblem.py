@@ -180,8 +180,11 @@ class PriceSnapshot:
         else:
             if cluster.backend.is_device:
                 # device operands stay on device; the bundle call is the sync
-                price_op = prices.device_tensor()[t]
-                free_op = cluster.device_free_tensor()[t]
+                price_all = prices.device_tensor()
+                free_all = cluster.device_free_tensor()
+                with _trace.launch("snapshot_slot"):
+                    price_op = price_all[t]
+                    free_op = free_all[t]
             else:
                 # host operands; NumpyBackend dispatches to the reference
                 # reduction (kernels.pricing.price_bundle_numpy), which is the

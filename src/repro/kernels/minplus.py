@@ -34,6 +34,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..obs import trace as _trace
+
 _INF = float("inf")
 
 
@@ -133,7 +135,7 @@ def _get_pallas_minplus():
             vals = a_ref[...] + b_ref[...]      # (tile, P) broadcast over rows
             o_ref[...] = jnp.min(vals, axis=1, keepdims=True).T
 
-        def impl(A, b, interpret):
+        def minplus_pallas_step(A, b, interpret):
             P = A.shape[0]
             return pl.pallas_call(
                 kernel,
@@ -146,7 +148,8 @@ def _get_pallas_minplus():
                 name="minplus",
             )(A, b)
 
-        _pallas_minplus = jax.jit(impl, static_argnames="interpret")
+        _pallas_minplus = jax.jit(minplus_pallas_step,
+                                  static_argnames="interpret")
     return _pallas_minplus
 
 
@@ -157,7 +160,9 @@ def minplus_pallas(
 
     The Toeplitz operand is built host-side (O(Q^2), tiny); the kernel does
     the broadcast-add + min-reduce. Rows/cols are padded to the 128-lane
-    tile; padding is +inf-neutral (inf + inf = inf never wins a min).
+    tile; padding is +inf-neutral (inf + inf = inf never wins a min). The
+    call runs under a ``device.launch`` span and the read of its output
+    under a ``device.sync`` span, both with the site ``minplus``.
     Raises ``ValueError`` when the padded width would exceed ``MAX_P``."""
     import jax
 
@@ -179,8 +184,10 @@ def minplus_pallas(
     )
     b = np.full((1, P), big, dtype=np.float32)
     b[0, :Q1] = np.minimum(tcost, big)
-    cur32 = np.asarray(
-        _get_pallas_minplus()(A, b, interpret=interpret)[0])[:Q1]
+    fn = _get_pallas_minplus()
+    with _trace.launch("minplus"):
+        out = fn(A, b, interpret=interpret)
+    cur32 = _trace.device_get(out, "minplus")[0, :Q1]
     best = np.where(cur32 >= big, _INF, cur32.astype(np.float64))
     # backtracking pointers recovered host-side from the same operands
     # (standard for DP kernels: the device computes values, not argmins)

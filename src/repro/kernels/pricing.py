@@ -37,13 +37,18 @@ free=8.9999999/demand=3 rounding up through floor), so the Pallas wrapper
 computes them host-side in float64 with exactly the reference arithmetic.
 
 ``price_bundle`` dispatches and always returns five host float64 arrays —
-the snapshot's host sync point under the jax backend.
+the snapshot's host sync point under the jax backend. Each device path
+runs its kernel call under a ``device.launch`` span and its host read
+under a ``device.sync`` span (``repro.obs.trace``), both with the site
+``price_bundle`` (one slot) or ``price_bundle_batch`` (the fused pass).
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import numpy as np
+
+from ..obs import trace as _trace
 
 _jnp_bundle = None                     # lazily created jit
 _jnp_bundle_batch = None               # lazily created jit (fused multi-slot)
@@ -89,7 +94,7 @@ def _get_jnp_bundle():
         import jax
         import jax.numpy as jnp
 
-        def impl(price, free, wdem, sdem, gamma):
+        def bundle_jnp(price, free, wdem, sdem, gamma):
             TRACE_COUNTS["bundle_jnp"] += 1
             wprice = price @ wdem
             sprice = price @ sdem
@@ -106,7 +111,7 @@ def _get_jnp_bundle():
 
             return wprice, sprice, coloc, headroom(wdem), headroom(sdem)
 
-        _jnp_bundle = jax.jit(impl)
+        _jnp_bundle = jax.jit(bundle_jnp)
     return _jnp_bundle
 
 
@@ -118,9 +123,10 @@ def price_bundle_jnp(price, free, wdem: np.ndarray, sdem: np.ndarray,
     reference's per-resource order — equal to ulps, covered by the
     tolerance parity tests, never by the bit-parity ones."""
     fn = _get_jnp_bundle()
-    out = fn(price, free, np.asarray(wdem, dtype=np.float64),
-             np.asarray(sdem, dtype=np.float64), float(gamma))
-    return tuple(np.asarray(o, dtype=np.float64) for o in out)
+    with _trace.launch("price_bundle"):
+        out = fn(price, free, np.asarray(wdem, dtype=np.float64),
+                 np.asarray(sdem, dtype=np.float64), float(gamma))
+    return _trace.device_get(tuple(out), "price_bundle", np.float64)
 
 
 # ---------------------------------------------------------------- pallas
@@ -140,7 +146,8 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _get_pallas_bundle():
-    """jit: (W, H, R) prices + (8, Rp) weights -> (3, W*H) f32 reductions.
+    """jit: (W, H, R) or (H, R) prices + (8, Rp) weights -> (3, W*H) f32
+    reductions.
 
     The f32 cast and the zero padding to the (row tile, 128-lane) grid run
     on the device, so a device-resident price tensor never visits the
@@ -162,10 +169,10 @@ def _get_pallas_bundle():
                 preferred_element_type=jnp.float32,
             )                                          # (8, tile)
 
-        def impl(price, wmat, interpret):
+        def bundle_pallas(price, wmat, interpret):
             TRACE_COUNTS["bundle_pallas"] += 1
-            rows = price.shape[0] * price.shape[1]
-            R = price.shape[2]
+            R = price.shape[-1]
+            rows = price.size // R
             Rp = wmat.shape[1]
             tile = min(ROW_TILE, _round_up(rows, _LANES))
             Hp = _round_up(rows, tile)
@@ -183,7 +190,7 @@ def _get_pallas_bundle():
             )(P, wmat)
             return out[:3, :rows]
 
-        _pallas_bundle = jax.jit(impl, static_argnames="interpret")
+        _pallas_bundle = jax.jit(bundle_pallas, static_argnames="interpret")
     return _pallas_bundle
 
 
@@ -211,7 +218,8 @@ def _headroom_exact(free64: np.ndarray, dem: np.ndarray) -> np.ndarray:
 
 def price_bundle_batch_pallas(price, free, wdem: np.ndarray,
                               sdem: np.ndarray, gamma: float,
-                              interpret: Optional[bool] = None) -> Bundle:
+                              interpret: Optional[bool] = None,
+                              site: str = "price_bundle_batch") -> Bundle:
     """Pallas TPU kernel for the fused batch (float32 prices).
 
     The (W, H, R) price stack is flattened to one (W*H, R) operand and
@@ -219,18 +227,22 @@ def price_bundle_batch_pallas(price, free, wdem: np.ndarray,
     every slot of the plan. The head-room rows are computed host-side in
     float64 (see the module docstring: a float32 ratio can overestimate
     the integer head-room by a whole unit at exact-capacity boundaries,
-    which would let the snapshot advertise a worker that does not fit)."""
+    which would let the snapshot advertise a worker that does not fit).
+    ``site`` names the call in its device spans; a device-resident
+    ``free`` is read under its own ``device.sync`` (``<site>.free``)."""
     import jax
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    free64 = np.asarray(free, dtype=np.float64)
+    free64 = _trace.device_get(free, site + ".free", np.float64)
     wdem = np.asarray(wdem, dtype=np.float64)
     sdem = np.asarray(sdem, dtype=np.float64)
     W, H = free64.shape[0], free64.shape[1]
-    red = _get_pallas_bundle()(price, bundle_weights(wdem, sdem, gamma),
-                               interpret=interpret)
-    out = np.asarray(red, dtype=np.float64).reshape(3, W, H)
+    fn = _get_pallas_bundle()
+    with _trace.launch(site):
+        red = fn(price, bundle_weights(wdem, sdem, gamma),
+                 interpret=interpret)
+    out = _trace.device_get(red, site, np.float64).reshape(3, W, H)
     return (out[0], out[1], out[2],
             _headroom_exact(free64, wdem), _headroom_exact(free64, sdem))
 
@@ -239,8 +251,10 @@ def price_bundle_pallas(price, free, wdem: np.ndarray, sdem: np.ndarray,
                         gamma: float,
                         interpret: Optional[bool] = None) -> Bundle:
     """One slot's (H, R) reduction through the batch kernel (W = 1)."""
-    out = price_bundle_batch_pallas(price[None], np.asarray(free)[None],
-                                    wdem, sdem, gamma, interpret=interpret)
+    free = _trace.device_get(free, "price_bundle.free", np.float64)
+    out = price_bundle_batch_pallas(price, free[None], wdem, sdem,
+                                    gamma, interpret=interpret,
+                                    site="price_bundle")
     return tuple(o[0] for o in out)
 
 
@@ -283,7 +297,7 @@ def _get_jnp_bundle_batch():
         import jax
         import jax.numpy as jnp
 
-        def impl(price, free, wdem, sdem, gamma):
+        def bundle_batch_jnp(price, free, wdem, sdem, gamma):
             TRACE_COUNTS["bundle_batch_jnp"] += 1
             wprice = price @ wdem                       # (W, H)
             sprice = price @ sdem
@@ -300,7 +314,7 @@ def _get_jnp_bundle_batch():
 
             return wprice, sprice, coloc, headroom(wdem), headroom(sdem)
 
-        _jnp_bundle_batch = jax.jit(impl)
+        _jnp_bundle_batch = jax.jit(bundle_batch_jnp)
     return _jnp_bundle_batch
 
 
@@ -312,9 +326,10 @@ def price_bundle_batch_jnp(price, free, wdem: np.ndarray, sdem: np.ndarray,
     trips. Dot-order accumulation (tolerance-equal to the reference, like
     the per-slot jnp path)."""
     fn = _get_jnp_bundle_batch()
-    out = fn(price, free, np.asarray(wdem, dtype=np.float64),
-             np.asarray(sdem, dtype=np.float64), float(gamma))
-    return tuple(np.asarray(o, dtype=np.float64) for o in out)
+    with _trace.launch("price_bundle_batch"):
+        out = fn(price, free, np.asarray(wdem, dtype=np.float64),
+                 np.asarray(sdem, dtype=np.float64), float(gamma))
+    return _trace.device_get(tuple(out), "price_bundle_batch", np.float64)
 
 
 def price_bundle_batch(price, free, wdem: np.ndarray, sdem: np.ndarray,

@@ -2,9 +2,10 @@
 
 Three pieces (docs/OBSERVABILITY.md):
 
-* ``obs.trace``   — span/tracer over the offer phases, Chrome-trace
-  JSON + per-phase aggregate table (``REPRO_TRACE=1`` or
-  ``SimEngine(trace=...)`` to enable; no-op singleton otherwise).
+* ``obs.trace``   — span/tracer over the offer phases and the device
+  boundary, per-phase aggregate table, spans mirrored into a
+  ``jax.profiler`` capture (``REPRO_TRACE=1`` or ``SimEngine(trace=...)``
+  to enable; no-op singleton otherwise).
 * ``obs.metrics`` — process-wide counter/gauge/histogram registry with
   Prometheus-style ``render()``; replaces scattered warn-once paths.
 * ``obs.pd_gap``  — realized primal utility vs dual objective from the

@@ -22,22 +22,45 @@ Span taxonomy (names are dotted phases; nesting gives the tree):
 ``plan.classify``}, ``lp.solve`` > {``lp.replay``, ``lp.simplex``},
 ``plan.resolve`` > ``plan.finish``, ``dp.sweep``} and ``offer.commit``;
 the simulator adds ``sim.advance``/``sim.arrivals``/``sim.checkpoint``/
-``sim.recover`` around the engine loop and ``offer.batch`` per arrival
-batch.
+``sim.recover`` around the engine loop, ``sim.slot`` over each slot of
+the loop (the root of everything the engine does in it), and
+``offer.batch`` per arrival batch. Every device boundary of the jax path
+is a leaf under the layer span that issued it: ``device.launch`` over a
+host call that enqueues device work (its dispatch, with the implicit
+host->device copy of numpy arguments) and ``device.sync`` over a
+blocking device->host read, each with a ``site`` attribute naming the
+op (``launch(site)``, ``sync(site)``, ``device_get(x, site)``).
 
-Exports: ``Tracer.chrome_trace()`` (Chrome ``chrome://tracing`` /
-Perfetto JSON, "X" complete events in microseconds) and
-``Tracer.phase_table()`` (per-name count/total/self/mean/max aggregate —
-self-times partition wall exactly, so ``sum(self_s)`` over all phases is
-the traced coverage of a run).
+Export: ``Tracer.phase_table()`` (per-name count/total/self/mean/max
+aggregate — self-times partition wall exactly, so ``sum(self_s)`` over
+all phases is the traced coverage of a run). With a tracer installed and
+jax already imported, each span also opens a
+``jax.profiler.TraceAnnotation`` of its own name, so a profiler capture
+(``jax.profiler.trace(..., create_perfetto_trace=True)``) holds every
+span on the profiler's clock beside the device ops. This module never
+imports jax itself.
 """
 from __future__ import annotations
 
-import json
 import os
+import sys
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+import numpy as np
+
+#: ``jax.profiler.TraceAnnotation`` once jax has been imported by someone
+#: else; looked up by the first span that finds jax in ``sys.modules``
+_annotation_cls = None
+
+
+def _annotation():
+    global _annotation_cls
+    if _annotation_cls is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
 
 
 class Span:
@@ -45,7 +68,7 @@ class Span:
     the module-level ``span()`` when tracing is enabled."""
 
     __slots__ = ("name", "attrs", "t0", "dur", "depth", "parent", "index",
-                 "child_dur", "_tracer")
+                 "child_dur", "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -57,6 +80,7 @@ class Span:
         self.parent = -1          # index into tracer.spans, -1 = root
         self.index = -1
         self.child_dur = 0.0      # closed children's wall, for self-time
+        self._ann = None          # the mirrored profiler annotation, open
 
     def set(self, **kv: Any) -> "Span":
         self.attrs.update(kv)
@@ -74,8 +98,17 @@ class Span:
         self.index = len(tr.spans)
         tr.spans.append(self)
         stack.append(self)
+        ann = _annotation_cls or _annotation()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
+
+    def _close_annotation(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
 
     def __exit__(self, et, ev, tb) -> bool:
         end = time.perf_counter()
@@ -88,8 +121,10 @@ class Span:
             if leaked.dur is None:
                 leaked.dur = end - leaked.t0
                 leaked.attrs["leaked"] = True
+            leaked._close_annotation()
         if stack:
             stack.pop()
+        self._close_annotation()
         self.dur = end - self.t0
         if et is not None:
             self.attrs["error"] = et.__name__
@@ -124,13 +159,13 @@ class Tracer:
 
     Spans are appended in start order; ``spans[i].parent`` indexes the
     enclosing span (-1 for roots). The tracer itself is cheap enough to
-    deepcopy (plain lists), so a checkpointed engine can carry one.
+    deepcopy (plain lists; a closed span holds no profiler annotation), so
+    a checkpointed engine can carry one.
     """
 
     def __init__(self) -> None:
         self.spans: List[Span] = []
         self._stack: List[Span] = []
-        self.origin = time.perf_counter()
 
     # -------------------------------------------------------------- API
     def span(self, name: str, **attrs: Any) -> Span:
@@ -139,7 +174,6 @@ class Tracer:
     def reset(self) -> None:
         self.spans = []
         self._stack = []
-        self.origin = time.perf_counter()
 
     def well_formed(self) -> bool:
         """No open spans, every span closed, parents precede children."""
@@ -154,26 +188,7 @@ class Tracer:
                 return False
         return True
 
-    # ---------------------------------------------------------- exports
-    def chrome_trace(self) -> Dict[str, Any]:
-        """Chrome-trace/Perfetto JSON: "X" (complete) events, µs."""
-        events = []
-        for sp in self.spans:
-            events.append({
-                "name": sp.name,
-                "ph": "X",
-                "ts": (sp.t0 - self.origin) * 1e6,
-                "dur": (sp.dur or 0.0) * 1e6,
-                "pid": 0,
-                "tid": 0,
-                "args": {k: v for k, v in sp.attrs.items()},
-            })
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def dump_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-
+    # ----------------------------------------------------------- export
     def phase_table(self) -> Dict[str, Dict[str, float]]:
         """Per-phase aggregate keyed by span name.
 
@@ -236,6 +251,37 @@ def span(name: str, **attrs: Any):
     if tr is None:
         return _NULL_SPAN
     return Span(tr, name, attrs)
+
+
+def launch(site: str):
+    """``device.launch`` span over a host call that enqueues device work;
+    ``site`` names the op. No-op singleton when off."""
+    tr = _tracer
+    if tr is None:
+        return _NULL_SPAN
+    return Span(tr, "device.launch", {"site": site})
+
+
+def sync(site: str):
+    """``device.sync`` span over a blocking device->host read; ``site``
+    names the op. No-op singleton when off."""
+    tr = _tracer
+    if tr is None:
+        return _NULL_SPAN
+    return Span(tr, "device.sync", {"site": site})
+
+
+def device_get(x, site: str, dtype=None):
+    """Host copy of a device array, or of a tuple of them, under one
+    ``device.sync`` span: the read blocks until the device has produced
+    the value. Returns numpy arrays (of ``dtype`` when given); a numpy
+    array is already on the host and passes through with no span."""
+    if isinstance(x, np.ndarray):
+        return np.asarray(x, dtype=dtype)
+    with sync(site):
+        if isinstance(x, tuple):
+            return tuple(np.asarray(a, dtype=dtype) for a in x)
+        return np.asarray(x, dtype=dtype)
 
 
 def annotate(**kv: Any) -> None:

@@ -823,179 +823,9 @@ class SimEngine:
 
     def _loop(self) -> SimReport:
         while self._t < self.max_slots:
-            t = self._t
-            if (self.checkpoint_every is not None
-                    and t % self.checkpoint_every == 0
-                    and (self._checkpoint is None
-                         or self._checkpoint.slot != t)):
-                with _trace.span("sim.checkpoint", t=t):
-                    self._take_checkpoint(t)
-            if self.kill_at is not None and t == self.kill_at:
-                raise SimKilled(f"engine killed at slot {t} (kill_at)")
-            while self._pending is not None and self._pending.time <= t:
-                self.queue.push(self._pending)
-                self._pending = self._pull()
-            busy = bool(self._active) or bool(self._awaiting)
-            if not busy and not len(self.queue) and self._pending is None:
-                break
-            if self._batched and not busy and self.queue.peek_time() != t:
-                # idle fast-forward: nothing is active or awaiting and the
-                # next event lies beyond this slot, so every intervening
-                # slot is an exact no-op except its metrics row (the
-                # ledger is empty — completed/preempted/departed jobs all
-                # released their rows — so utilization and the ledger
-                # check are constant across the gap). Jump to the next
-                # event, stopping at checkpoint boundaries and kill_at so
-                # snapshot slots and the kill slot match the oracle.
-                nt = self.queue.peek_time()
-                if nt is None:
-                    nt = self._pending.time  # pending exists or we broke
-                elif self._pending is not None:
-                    nt = min(nt, self._pending.time)
-                target = min(nt, self.max_slots)
-                if self.kill_at is not None and t < self.kill_at:
-                    target = min(target, self.kill_at)
-                if self.checkpoint_every is not None:
-                    k = self.checkpoint_every
-                    target = min(target, (t // k + 1) * k)
-                if target > t:
-                    with _trace.span("sim.advance", t=t):
-                        self.window.advance_to(t)
-                    util = self.window.utilization_now()
-                    degraded = tuple(sorted(
-                        h for h, incs in self._incidents.items() if incs
-                    ))
-                    for ts in range(t, target):
-                        self.metrics.record_slot(ts, util, 0, 0,
-                                                 degraded=degraded)
-                    self._t = target
-                    continue
-            with _trace.span("sim.advance", t=t):
-                self.window.advance_to(t)
-
-            batch: List[Event] = []
-            departures: List[int] = []
-            failures: List[int] = []
-            evs = (self.queue.pop_slot(t) if self._batched
-                   else self.queue.pop_until(t))
-            for ev in evs:
-                if ev.kind == EventKind.MACHINE_UP:
-                    self._machine_up(ev, t)
-                elif ev.kind == EventKind.MACHINE_DOWN:
-                    self._machine_down(ev, t)
-                elif ev.kind == EventKind.FAILURE:
-                    if self._batched:
-                        failures.append(ev.subject())
-                    else:
-                        self._fail(ev.subject(), t)
-                elif ev.kind == EventKind.ARRIVAL:
-                    batch.append(ev)
-                elif ev.kind == EventKind.DEPARTURE:
-                    # exogenous departure (a trace may model jobs giving up
-                    # on their own clock); applied after the slot's arrival
-                    # batch so a same-slot DEPARTURE+ARRIVAL pair still
-                    # departs instead of being dropped against a job state
-                    # that does not exist yet
-                    departures.append(ev.subject())
-                else:
-                    # COMPLETION/PREEMPT/SLOT are engine-emitted
-                    # notifications, never queue input — fail loud rather
-                    # than silently dropping a mis-routed event
-                    raise ValueError(
-                        f"unsupported queued event kind {ev.kind!r} at t={t}"
-                    )
-            if failures:
-                # all of a slot's plain FAILUREs pop before its ARRIVALs
-                # (kind priority), so the grouped fold sits exactly where
-                # the oracle's per-event _fail calls were
-                self._fail_group(failures, t)
-            if batch:
-                with _trace.span("sim.arrivals", t=t, jobs=len(batch)):
-                    self._handle_arrivals(batch, t)
-            for job_id in departures:
-                js = self.states.get(job_id)
-                if js is None or js.finished or not js.active \
-                        or self.metrics.outcome(
-                            job_id, js.orig_arrival).first_service is not None:
-                    self.metrics.count("departure_moot")  # served/done/unknown
-                    continue
-                self._depart(job_id, t)
-            if self.policy.slot_driven:
-                sts = self.states
-                if self._batched:
-                    # _active_order is the oracle's sorted() result kept
-                    # incrementally: keys are (arrival, job_id) fixed at
-                    # activation, and a job's arrival only changes on a
-                    # requeue, which happens while deactivated
-                    actives = [
-                        sts[jid].job for _, jid in self._active_order
-                        if not sts[jid].finished and sts[jid].down_at != t
-                    ]
-                else:
-                    actives = sorted(
-                        (sts[jid].job for jid in self._active
-                         if not sts[jid].finished
-                         and sts[jid].down_at != t),
-                        key=lambda j: (j.arrival, j.job_id),
-                    )
-                if actives:
-                    # the progress payload is only read by fairness-aware
-                    # slot policies (Dorm); the batched engine skips
-                    # building it for policies that declare wants_progress
-                    # False — the Event differs but no decision can
-                    progress = None
-                    if not self._batched or getattr(
-                            self.policy, "wants_progress", True):
-                        progress = {
-                            j.job_id: sts[j.job_id].progress
-                            for j in actives
-                        }
-                    self.policy.offer(
-                        Event(
-                            time=t, kind=EventKind.SLOT, jobs=tuple(actives),
-                            progress=progress,
-                        ),
-                        self.window,
-                    )
-            if self.check_ledger and self.window.oversubscribed():
-                raise LedgerInvariantError(
-                    slot=t, policy=self.policy.name,
-                    report=SimReport(
-                        summary=self.metrics.summary(),
-                        metrics=self.metrics,
-                        states=self.states,
-                        slots_run=t,
-                    ),
-                    journal_tail=tuple(self.journal[-64:]),
-                )
-            if self._batched:
-                self._account_progress_batched(t)
-                self._check_patience_batched(t)
-            else:
-                self._account_progress(t)
-                self._check_patience(t)
-            # elastic reshape triggers run AFTER progress/patience in both
-            # modes, through the one shared scan — mode parity by
-            # construction
-            self._check_reshapes(t)
-            active = len(self._active)
-            if self._batched:
-                queued = len(self._never_served)
-            else:
-                queued = sum(
-                    1 for jid in self._active
-                    if self.metrics.outcome(
-                        jid, self.states[jid].orig_arrival,
-                    ).first_service is None
-                )
-            degraded = tuple(sorted(
-                h for h, incs in self._incidents.items() if incs
-            ))
-            self.metrics.record_slot(
-                t, self.window.utilization_now(), active, queued,
-                degraded=degraded,
-            )
-            self._t = t + 1
+            with _trace.span("sim.slot", t=self._t) as slot:
+                if not self._slot(slot):
+                    break
         summary = self.metrics.summary()
         health = getattr(self.policy, "health_stats", None)
         if callable(health):
@@ -1017,6 +847,186 @@ class SimEngine:
             slots_run=self._t,
             pd_gap=pd_snap,
         )
+
+    def _slot(self, slot) -> bool:
+        """One iteration of the slot loop, under its ``sim.slot`` span
+        (an idle fast-forward over several slots is one iteration, with a
+        ``slots`` attribute). Returns False when the run is over."""
+        t = self._t
+        if (self.checkpoint_every is not None
+                and t % self.checkpoint_every == 0
+                and (self._checkpoint is None
+                     or self._checkpoint.slot != t)):
+            with _trace.span("sim.checkpoint", t=t):
+                self._take_checkpoint(t)
+        if self.kill_at is not None and t == self.kill_at:
+            raise SimKilled(f"engine killed at slot {t} (kill_at)")
+        while self._pending is not None and self._pending.time <= t:
+            self.queue.push(self._pending)
+            self._pending = self._pull()
+        busy = bool(self._active) or bool(self._awaiting)
+        if not busy and not len(self.queue) and self._pending is None:
+            return False
+        if self._batched and not busy and self.queue.peek_time() != t:
+            # idle fast-forward: nothing is active or awaiting and the
+            # next event lies beyond this slot, so every intervening
+            # slot is an exact no-op except its metrics row (the
+            # ledger is empty — completed/preempted/departed jobs all
+            # released their rows — so utilization and the ledger
+            # check are constant across the gap). Jump to the next
+            # event, stopping at checkpoint boundaries and kill_at so
+            # snapshot slots and the kill slot match the oracle.
+            nt = self.queue.peek_time()
+            if nt is None:
+                nt = self._pending.time  # pending exists or we broke
+            elif self._pending is not None:
+                nt = min(nt, self._pending.time)
+            target = min(nt, self.max_slots)
+            if self.kill_at is not None and t < self.kill_at:
+                target = min(target, self.kill_at)
+            if self.checkpoint_every is not None:
+                k = self.checkpoint_every
+                target = min(target, (t // k + 1) * k)
+            if target > t:
+                with _trace.span("sim.advance", t=t):
+                    self.window.advance_to(t)
+                util = self.window.utilization_now()
+                degraded = tuple(sorted(
+                    h for h, incs in self._incidents.items() if incs
+                ))
+                for ts in range(t, target):
+                    self.metrics.record_slot(ts, util, 0, 0,
+                                             degraded=degraded)
+                self._t = target
+                slot.set(slots=target - t)
+                return True
+        with _trace.span("sim.advance", t=t):
+            self.window.advance_to(t)
+
+        batch: List[Event] = []
+        departures: List[int] = []
+        failures: List[int] = []
+        evs = (self.queue.pop_slot(t) if self._batched
+               else self.queue.pop_until(t))
+        for ev in evs:
+            if ev.kind == EventKind.MACHINE_UP:
+                self._machine_up(ev, t)
+            elif ev.kind == EventKind.MACHINE_DOWN:
+                self._machine_down(ev, t)
+            elif ev.kind == EventKind.FAILURE:
+                if self._batched:
+                    failures.append(ev.subject())
+                else:
+                    self._fail(ev.subject(), t)
+            elif ev.kind == EventKind.ARRIVAL:
+                batch.append(ev)
+            elif ev.kind == EventKind.DEPARTURE:
+                # exogenous departure (a trace may model jobs giving up
+                # on their own clock); applied after the slot's arrival
+                # batch so a same-slot DEPARTURE+ARRIVAL pair still
+                # departs instead of being dropped against a job state
+                # that does not exist yet
+                departures.append(ev.subject())
+            else:
+                # COMPLETION/PREEMPT/SLOT are engine-emitted
+                # notifications, never queue input — fail loud rather
+                # than silently dropping a mis-routed event
+                raise ValueError(
+                    f"unsupported queued event kind {ev.kind!r} at t={t}"
+                )
+        if failures:
+            # all of a slot's plain FAILUREs pop before its ARRIVALs
+            # (kind priority), so the grouped fold sits exactly where
+            # the oracle's per-event _fail calls were
+            self._fail_group(failures, t)
+        if batch:
+            with _trace.span("sim.arrivals", t=t, jobs=len(batch)):
+                self._handle_arrivals(batch, t)
+        for job_id in departures:
+            js = self.states.get(job_id)
+            if js is None or js.finished or not js.active \
+                    or self.metrics.outcome(
+                        job_id, js.orig_arrival).first_service is not None:
+                self.metrics.count("departure_moot")  # served/done/unknown
+                continue
+            self._depart(job_id, t)
+        if self.policy.slot_driven:
+            sts = self.states
+            if self._batched:
+                # _active_order is the oracle's sorted() result kept
+                # incrementally: keys are (arrival, job_id) fixed at
+                # activation, and a job's arrival only changes on a
+                # requeue, which happens while deactivated
+                actives = [
+                    sts[jid].job for _, jid in self._active_order
+                    if not sts[jid].finished and sts[jid].down_at != t
+                ]
+            else:
+                actives = sorted(
+                    (sts[jid].job for jid in self._active
+                     if not sts[jid].finished
+                     and sts[jid].down_at != t),
+                    key=lambda j: (j.arrival, j.job_id),
+                )
+            if actives:
+                # the progress payload is only read by fairness-aware
+                # slot policies (Dorm); the batched engine skips
+                # building it for policies that declare wants_progress
+                # False — the Event differs but no decision can
+                progress = None
+                if not self._batched or getattr(
+                        self.policy, "wants_progress", True):
+                    progress = {
+                        j.job_id: sts[j.job_id].progress
+                        for j in actives
+                    }
+                self.policy.offer(
+                    Event(
+                        time=t, kind=EventKind.SLOT, jobs=tuple(actives),
+                        progress=progress,
+                    ),
+                    self.window,
+                )
+        if self.check_ledger and self.window.oversubscribed():
+            raise LedgerInvariantError(
+                slot=t, policy=self.policy.name,
+                report=SimReport(
+                    summary=self.metrics.summary(),
+                    metrics=self.metrics,
+                    states=self.states,
+                    slots_run=t,
+                ),
+                journal_tail=tuple(self.journal[-64:]),
+            )
+        if self._batched:
+            self._account_progress_batched(t)
+            self._check_patience_batched(t)
+        else:
+            self._account_progress(t)
+            self._check_patience(t)
+        # elastic reshape triggers run AFTER progress/patience in both
+        # modes, through the one shared scan — mode parity by
+        # construction
+        self._check_reshapes(t)
+        active = len(self._active)
+        if self._batched:
+            queued = len(self._never_served)
+        else:
+            queued = sum(
+                1 for jid in self._active
+                if self.metrics.outcome(
+                    jid, self.states[jid].orig_arrival,
+                ).first_service is None
+            )
+        degraded = tuple(sorted(
+            h for h, incs in self._incidents.items() if incs
+        ))
+        self.metrics.record_slot(
+            t, self.window.utilization_now(), active, queued,
+            degraded=degraded,
+        )
+        self._t = t + 1
+        return True
 
     def admission_latency(self) -> Dict[str, float]:
         """Wall-clock SLO accounting of the ARRIVAL-batch offer path:

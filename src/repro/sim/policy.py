@@ -420,43 +420,48 @@ class PDORSPolicy(SchedulingPolicy):
         (``SolvePlan.fresh`` guards against a stale plan ever being
         consumed) — re-stacking after every admission would cost O(B^2)
         plan builds on admit-heavy batches."""
-        dec = Decision()
+        # the batch's plans are freed when _offer_batch returns, inside
+        # the span: their teardown is the offer's cost, not the engine's
         with _trace.span("offer.batch", jobs=len(event.jobs)):
-            self.prices.prewarm()
-            plans: Dict[int, Optional[SolvePlan]] = {}
-            offer_env = {}
-            if self.base_cfg.use_plan:
-                for job in event.jobs:
-                    cfg, rng = self._offer_cfg(job)
-                    offer_env[job.job_id] = (cfg, rng)
-                    rel = view.rel_job(job)
-                    if rel.arrival < view.lookahead:
-                        plan = SolvePlan(rel, view.cluster, self.prices,
-                                         cfg, rel.arrival,
-                                         view.lookahead - 1,
-                                         quanta=self.quanta,
-                                         warm=self._warm_for(view, rel))
-                        self._harvest_bundles(view, rel, plan)
-                    else:
-                        plan = None
-                    plans[job.job_id] = plan
-                solve_plans([p for p in plans.values() if p is not None])
+            return self._offer_batch(event, view)
+
+    def _offer_batch(self, event: Event, view: RollingWindow) -> Decision:
+        dec = Decision()
+        self.prices.prewarm()
+        plans: Dict[int, Optional[SolvePlan]] = {}
+        offer_env = {}
+        if self.base_cfg.use_plan:
             for job in event.jobs:
-                cfg, rng = offer_env.get(job.job_id, (None, None))
-                schedule = self._offer_one(
-                    job, view, plan=plans.get(job.job_id), cfg=cfg, rng=rng,
-                )
-                if schedule is None:
-                    dec.admitted[job.job_id] = False
-                    continue
-                with _trace.span("offer.commit", job=int(job.job_id),
-                                 slots=len(schedule)):
-                    view.commit_schedule(job, schedule)
-                dec.admitted[job.job_id] = True
-                dec.schedules[job.job_id] = schedule
-                # admission repriced every committed slot: rebuild the
-                # price tensor once for the remaining jobs of the batch
-                self.prices.prewarm()
+                cfg, rng = self._offer_cfg(job)
+                offer_env[job.job_id] = (cfg, rng)
+                rel = view.rel_job(job)
+                if rel.arrival < view.lookahead:
+                    plan = SolvePlan(rel, view.cluster, self.prices,
+                                     cfg, rel.arrival,
+                                     view.lookahead - 1,
+                                     quanta=self.quanta,
+                                     warm=self._warm_for(view, rel))
+                    self._harvest_bundles(view, rel, plan)
+                else:
+                    plan = None
+                plans[job.job_id] = plan
+            solve_plans([p for p in plans.values() if p is not None])
+        for job in event.jobs:
+            cfg, rng = offer_env.get(job.job_id, (None, None))
+            schedule = self._offer_one(
+                job, view, plan=plans.get(job.job_id), cfg=cfg, rng=rng,
+            )
+            if schedule is None:
+                dec.admitted[job.job_id] = False
+                continue
+            with _trace.span("offer.commit", job=int(job.job_id),
+                             slots=len(schedule)):
+                view.commit_schedule(job, schedule)
+            dec.admitted[job.job_id] = True
+            dec.schedules[job.job_id] = schedule
+            # admission repriced every committed slot: rebuild the
+            # price tensor once for the remaining jobs of the batch
+            self.prices.prewarm()
         return dec
 
 
@@ -513,7 +518,7 @@ class PDORSReferencePolicy(SchedulingPolicy):
                 for h in range(cl.num_machines)
             ]
         ref = _ref.Cluster(machines=machines, horizon=cl.horizon)
-        used = cl.backend.to_host(cl._used)
+        used = cl.backend.to_host(cl._used, "to_host:used")
         for t, h, k in zip(*np.nonzero(used)):
             ref._used[(int(t), int(h), cl.resources[int(k)])] = float(
                 used[t, h, k]

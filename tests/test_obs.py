@@ -2,8 +2,9 @@
 instrumentation on vs off, span-tree well-formedness under exceptions,
 registry semantics + Prometheus rendering, recover()-determinism of the
 published gauges, primal-dual gap telemetry, P-squared streaming
-quantiles, and the Chrome-trace export schema."""
-import json
+quantiles, the device launch/sync spans of the jax path, and the spans'
+mirror in the profiler trace."""
+from collections import Counter
 from contextlib import nullcontext
 
 import numpy as np
@@ -69,11 +70,12 @@ def _fingerprint(records):
     return out
 
 
-def _run_offers(H, T, N, scale, rng_mode, seed=0, tracer=None, cfg_kw=None):
+def _run_offers(H, T, N, scale, rng_mode, seed=0, tracer=None, cfg_kw=None,
+                backend="numpy"):
     wcfg = WorkloadConfig(num_jobs=N, horizon=T, seed=seed,
                           workload_scale=scale)
     jobs = sorted(synthetic_jobs(wcfg), key=lambda j: (j.arrival, j.job_id))
-    cluster = make_cluster(H, T)
+    cluster = make_cluster(H, T, backend=backend)
     params = estimate_price_params(jobs, cluster, cluster.horizon)
     sched = PDORS(cluster, params,
                   cfg=SubproblemConfig(rng_mode=rng_mode, **(cfg_kw or {})),
@@ -94,15 +96,27 @@ REGIMES = [(5, 8, 8, 0.003), (5, 8, 8, 0.3), (8, 10, 10, 0.05),
            (6, 12, 9, 0.5)]
 
 
+#: the jax backend on the CPU, with the Pallas min-plus kernel (interpret
+#: mode) so that every device span of the path is on
+JAX_PALLAS = dict(backend="jax", cfg_kw=dict(minplus_backend="pallas"))
+
+
 @pytest.mark.parametrize("rng_mode", ["compat", "derived"])
-@pytest.mark.parametrize("H,T,N,scale", REGIMES)
-def test_tracing_never_changes_decisions(H, T, N, scale, rng_mode):
-    base = _run_offers(H, T, N, scale, rng_mode)
+@pytest.mark.parametrize("H,T,N,scale,path", [
+    *[(*regime, "numpy") for regime in REGIMES],
+    (5, 8, 6, 0.003, "jax"), (8, 10, 8, 0.05, "jax"),
+])
+def test_tracing_never_changes_decisions(H, T, N, scale, path, rng_mode):
+    kw = JAX_PALLAS if path == "jax" else {}
+    base = _run_offers(H, T, N, scale, rng_mode, **kw)
     tracer = Tracer()
-    traced = _run_offers(H, T, N, scale, rng_mode, tracer=tracer)
+    traced = _run_offers(H, T, N, scale, rng_mode, tracer=tracer, **kw)
     assert traced == base               # bit-identical, slot-for-slot
     assert tracer.spans, "tracing enabled but no spans recorded"
     assert tracer.well_formed()
+    if path == "jax":
+        names = {sp.name for sp in tracer.spans}
+        assert {"device.launch", "device.sync"} <= names
 
 
 def test_offer_span_tree_shape():
@@ -152,8 +166,9 @@ def test_span_tree_well_formed_under_ledger_invariant_error():
                     max_slots=10, trace=tracer)
     with pytest.raises(LedgerInvariantError):
         eng.run([Event(time=0, kind=EventKind.ARRIVAL, job=small_job())])
-    # the invariant check fires between spans, so no span carries the
-    # error attr — the contract is that the unwind leaves the tree closed
+    # the invariant check fires inside the slot's sim.slot span, which
+    # records the error — the contract is that the unwind leaves the
+    # tree closed
     assert tracer.spans
     assert tracer.well_formed()
 
@@ -338,22 +353,152 @@ def test_streaming_collector_matches_exact_summary_schema():
         MetricsCollector(["gpu"], mode="bogus")
 
 
-# ----------------------------------------------------- chrome trace
-def test_chrome_trace_schema_and_dump(tmp_path):
+# --------------------------------------------------- device boundary
+def _enclosing(tracer, sp, name):
+    """The nearest enclosing span called ``name``, or None."""
+    while sp.parent >= 0:
+        sp = tracer.spans[sp.parent]
+        if sp.name == name:
+            return sp
+    return None
+
+
+def test_minplus_syncs_match_each_dp_sweeps_steps():
+    """One launch and one sync of the min-plus kernel per DP step, under
+    the dp.sweep that issued them, each a leaf."""
     tracer = Tracer()
-    _run_offers(5, 8, 6, 0.05, "compat", tracer=tracer)
-    doc = tracer.chrome_trace()
-    assert set(doc) == {"traceEvents", "displayTimeUnit"}
-    assert doc["displayTimeUnit"] == "ms"
-    assert doc["traceEvents"]
-    for ev in doc["traceEvents"]:
-        assert set(ev) == {"name", "ph", "ts", "dur", "pid", "tid", "args"}
-        assert ev["ph"] == "X"
-        assert ev["ts"] >= 0.0 and ev["dur"] >= 0.0
-        assert isinstance(ev["args"], dict)
-    path = tmp_path / "trace.json"
-    tracer.dump_chrome_trace(str(path))
-    assert json.loads(path.read_text())["traceEvents"]
+    _run_offers(5, 8, 6, 0.05, "compat", tracer=tracer, **JAX_PALLAS)
+    sweeps = [sp for sp in tracer.spans if sp.name == "dp.sweep"]
+    assert sweeps
+    per_sweep = {sp.index: Counter() for sp in sweeps}
+    for sp in tracer.spans:
+        if sp.name in ("device.launch", "device.sync"):
+            assert "site" in sp.attrs
+            assert all(c.parent != sp.index for c in tracer.spans)  # leaf
+            if sp.attrs["site"] == "minplus":
+                owner = _enclosing(tracer, sp, "dp.sweep")
+                assert owner is not None
+                per_sweep[owner.index][sp.name] += 1
+    for sw in sweeps:
+        assert per_sweep[sw.index]["device.sync"] == sw.attrs["slots"]
+        assert per_sweep[sw.index]["device.launch"] == sw.attrs["slots"]
+
+
+def test_device_spans_nest_under_their_layer_span():
+    """The engine's ledger advance launches under sim.advance and its
+    oversubscription check under sim.slot; every span of the slot loop
+    hangs under one sim.slot per iteration."""
+    tcfg = TraceConfig(num_jobs=8, seed=3, arrival_rate=0.6)
+    cl = make_cluster(4, 12, backend="jax")
+    params = calibrate_prices(tcfg, make_cluster(4, 12), n=16)
+    tracer = Tracer()
+    eng = SimEngine(RollingWindow(cl),
+                    make_policy("pdors", price_params=params, quanta=8),
+                    seed=3, max_slots=200, patience=tcfg.patience,
+                    engine_mode="batched", trace=tracer)
+    eng.run(stream(tcfg))
+    assert tracer.well_formed()
+    # every root until the loop ends is a slot (the run's summary reads
+    # prices after it)
+    roots = [sp.name for sp in tracer.spans if sp.parent < 0]
+    last = len(roots) - roots[::-1].index("sim.slot")
+    assert set(roots[:last]) == {"sim.slot"}
+    sites = Counter((tracer.spans[sp.parent].name, sp.name, sp.attrs["site"])
+                    for sp in tracer.spans if sp.name.startswith("device."))
+    assert sites[("sim.advance", "device.launch", "ledger_advance")] > 0
+    assert sites[("sim.slot", "device.sync", "oversubscribed")] > 0
+    assert any(sp.name == "offer.batch"
+               and tracer.spans[sp.parent].name == "sim.arrivals"
+               for sp in tracer.spans)
+
+
+def test_profiler_host_plane_holds_every_span(tmp_path):
+    """With a tracer installed, each span opens a TraceAnnotation of its
+    name: the captured .xplane.pb's host plane holds one event per span."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracer = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run_offers(5, 8, 4, 0.05, "compat", tracer=tracer, **JAX_PALLAS)
+    finally:
+        jax.profiler.stop_trace()
+    want = Counter(sp.name for sp in tracer.spans)
+    assert want["device.sync"] and want["dp.sweep"]
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    got = Counter()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                got.update(ev.name for ev in line.events if ev.name in want)
+    assert got == want
+
+
+def test_no_tracer_device_sites_are_the_shared_noop(monkeypatch):
+    """Off: the launch/sync helpers return the shared no-op span, a host
+    array passes device_get untouched, and no annotation is created."""
+    made = []
+
+    class Annotation:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    import jax  # noqa: F401  (the annotation is only looked up with jax)
+    monkeypatch.setattr(obs_trace, "_annotation_cls", Annotation)
+    prev = obs_trace.get_tracer()
+    obs_trace.install(None)
+    try:
+        assert obs_trace.launch("minplus") is obs_trace._NULL_SPAN
+        assert obs_trace.sync("to_host:price") is obs_trace._NULL_SPAN
+        host = np.arange(3.0)
+        assert obs_trace.device_get(host, "x") is host
+        _run_offers(5, 8, 4, 0.05, "compat", **JAX_PALLAS)
+        assert made == []
+        tracer = Tracer()
+        _run_offers(5, 8, 4, 0.05, "compat", tracer=tracer, **JAX_PALLAS)
+        assert made == [sp.name for sp in tracer.spans]
+    finally:
+        obs_trace.install(prev)
+
+
+def test_traced_numpy_run_never_imports_jax():
+    """repro.obs.trace imports without jax, and a traced numpy-backend run
+    mirrors nothing into a profiler: jax stays unimported."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    code = (
+        "import sys\n"
+        "from repro.obs import Tracer, trace\n"
+        "from repro.core import PDORS, make_cluster, synthetic_jobs, "
+        "WorkloadConfig, estimate_price_params\n"
+        "jobs = synthetic_jobs(WorkloadConfig(num_jobs=4, horizon=8, seed=0,"
+        " workload_scale=0.05))\n"
+        "cl = make_cluster(5, 8)\n"
+        "sched = PDORS(cl, estimate_price_params(jobs, cl, 8), quanta=8)\n"
+        "tracer = Tracer()\n"
+        "with trace.activate(tracer):\n"
+        "    for job in jobs:\n"
+        "        sched.offer(job)\n"
+        "assert tracer.spans and trace._annotation_cls is None\n"
+        "print('jax' in sys.modules)\n")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, PYTHONPATH=src, REPRO_TRACE="")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
 
 
 # ------------------------------------------------------- off-mode API

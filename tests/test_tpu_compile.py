@@ -79,6 +79,7 @@ def test_minplus_kernel_compiles(one_chip, P):
 
 @pytest.mark.parametrize("op", [
     "price_tensor", "free_tensor", "scatter_add", "scatter_sub",
+    "ledger_advance", "oversubscribed",
 ])
 def test_f64_ledger_ops_compile(one_chip, op):
     from repro.backend import get_backend
@@ -101,8 +102,13 @@ def test_f64_ledger_ops_compile(one_chip, op):
                         (ledger, _spec(one_chip, (), jnp.int64),
                          _spec(one_chip, (width,), jnp.int64),
                          _spec(one_chip, (width, R), f64))),
+        "ledger_advance": (be._advance_jit,
+                           (ledger, _spec(one_chip, (), jnp.int64))),
+        "oversubscribed": (be._over_jit,
+                           (ledger, cap, _spec(one_chip, (), f64))),
     }[op]
     with jax.enable_x64(True):
         compiled = fn.lower(*args).compile()
     out = compiled.memory_analysis()
-    assert out is None or out.output_size_in_bytes >= T * H * R * 8
+    want = 1 if op == "oversubscribed" else T * H * R * 8   # bool, or a ledger
+    assert out is None or out.output_size_in_bytes >= want
