@@ -7,8 +7,10 @@ One process, four phases, each printing its own lines:
   device   fail unless JAX's first device is a TPU;
   kernels  the row-tiled Pallas pricing kernel on a (64 x 1024, 4) price
            operand and the min-plus kernel at Q = 32, against their NumPy
-           references, and proof that each lowered to a Mosaic
-           ``tpu_custom_call`` (not interpret mode);
+           references; the min-plus sweep of 64 steps in one device call,
+           bit for bit against 64 single steps; and proof that the pricing
+           kernel and the sweep lowered to a Mosaic ``tpu_custom_call``
+           (not interpret mode);
   served   ``OfferService`` over ``PDORS`` on ``jax``-backend clusters:
            200 light jobs on 1024 machines x 64 slots, then 50 contended
            jobs on 256 x 32, all submitted concurrently;
@@ -161,9 +163,6 @@ def phase_kernels(slots: int = 64, machines: int = 1024, resources: int = 4,
         minplus.minplus_pallas(prev, tcost)
         mp_steady.append(time.perf_counter() - t0)
     P = minplus.ROW_TILE * -(-Q1 // minplus.ROW_TILE)
-    mp_mosaic = _lowered_to_mosaic(
-        minplus._get_pallas_minplus(), np.zeros((P, P), np.float32),
-        np.zeros((1, P), np.float32), interpret=interpret)
     ref_cur, ref_choice = minplus.minplus_numpy(prev, tcost)
     finite = np.isfinite(ref_cur)
     if not (np.isfinite(cur) == finite).all():
@@ -173,8 +172,38 @@ def phase_kernels(slots: int = 64, machines: int = 1024, resources: int = 4,
     if not ((choice < 0) == (ref_choice < 0)).all():
         raise AssertionError("min-plus kernel: backtrack pointers differ")
     log("kernels", f"minplus Q={quanta} P={P} first_s={mp_first:.6f} "
-                   f"steady_s={np.median(mp_steady):.6f} "
-                   f"tpu_custom_call={mp_mosaic} match=ok")
+                   f"steady_s={np.median(mp_steady):.6f} match=ok")
+
+    # the DP's path: one sweep of ``slots`` steps in one device call, bit
+    # for bit the single step fed its own output ``slots`` times
+    tcosts = rng.uniform(0.0, 100.0, (slots, Q1))
+    tcosts[rng.random((slots, Q1)) < 0.2] = np.inf
+    tcosts[:, 0] = 0.0
+    start = np.full(Q1, np.inf)
+    start[0] = 0.0
+    t0 = time.perf_counter()
+    best, bchoice = minplus.minplus_sweep_pallas(start, tcosts)
+    sw_first = time.perf_counter() - t0
+    sw_steady = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        minplus.minplus_sweep_pallas(start, tcosts)
+        sw_steady.append(time.perf_counter() - t0)
+    row = start
+    for i in range(slots):
+        row, ch = minplus.minplus_pallas(row, tcosts[i])
+        if not (np.array_equal(row, best[i])
+                and np.array_equal(ch, bchoice[i])):
+            raise AssertionError(
+                f"min-plus sweep: step {i} differs from the single step")
+    mp_mosaic = _lowered_to_mosaic(
+        minplus._get_pallas_sweep(), np.zeros(P, np.float32),
+        np.zeros((slots, P), np.float32), np.int32(slots),
+        interpret=interpret)
+    log("kernels", f"minplus_sweep Q={quanta} P={P} steps={slots} "
+                   f"first_s={sw_first:.6f} "
+                   f"steady_s={np.median(sw_steady):.6f} "
+                   f"tpu_custom_call={mp_mosaic} match=exact")
     return {"pricing_mosaic": price_mosaic, "minplus_mosaic": mp_mosaic}
 
 

@@ -20,10 +20,11 @@ i.e. a tropical vector-matrix product against the lower-triangular Toeplitz
 operand built from C[k-1] (see ``repro.kernels.minplus``). The step runs
 vectorized in NumPy by default (bit-identical to the scalar loop, so
 decisions never depend on the host); ``minplus_backend`` selects
-``"pallas"`` (float32 TPU kernel, auto-interpreting off-TPU) or
-``"scalar"`` (the pre-vectorization double loop, kept for parity tests
-and benchmarks). The cost table is a dense ``(k+1, Q+1)`` float64
-ndarray; the choice (backtracking) table mirrors it.
+``"pallas"`` (float32 TPU kernel, auto-interpreting off-TPU; all k steps
+run in one device call) or ``"scalar"`` (the pre-vectorization double
+loop, kept for parity tests and benchmarks). The cost table is a dense
+``(k+1, Q+1)`` float64 ndarray; the choice (backtracking) table mirrors
+it.
 
 The forward table C[t][u] = min cost to finish u units within [a_i, t]
 is shared across all completion-time candidates of Algorithm 2, which
@@ -37,7 +38,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..kernels.minplus import minplus_step
+from ..kernels.minplus import minplus_step, minplus_sweep_pallas
 from ..obs import trace as _trace
 from .cluster import Cluster
 from .job import Allocation, JobSpec
@@ -228,7 +229,12 @@ class WorkloadDP:
         back to the cluster's array backend's preference (None -> the
         bit-stable NumPy step for numpy; "pallas" only when the jax
         backend actually runs on a TPU — see
-        ``ArrayBackend.minplus_default``)."""
+        ``ArrayBackend.minplus_default``). On "pallas" the theta-cost
+        rows of all k slots are read first and the k steps run as one
+        device sweep (``minplus_sweep_pallas``), bit-identical to k
+        single Pallas steps; "numpy" and "scalar" step slot by slot on
+        the host. The ``dp.sweep`` span's ``device_calls`` attribute
+        counts the device calls the sweep made (1 or 0)."""
         a = self.job.arrival
         Q = self.quanta
         backend = self.cfg.minplus_backend
@@ -236,15 +242,25 @@ class WorkloadDP:
             backend = self.cluster.backend.minplus_default()
         self._ensure_plan(t_end)
         k = t_end - a + 1
-        with _trace.span("dp.sweep", slots=k, quanta=Q, backend=backend):
+        with _trace.span("dp.sweep", slots=k, quanta=Q,
+                         backend=backend) as sweep:
             C = np.full((k + 1, Q + 1), np.inf)
             C[0, 0] = 0.0
             choice = np.full((k + 1, Q + 1), -1, dtype=np.int64)
-            for t in range(a, t_end + 1):
-                tcost = self._theta_costs(t)
-                cur, ch = minplus_step(C[t - a], tcost, backend=backend)
-                C[t - a + 1] = cur
-                choice[t - a + 1] = ch
+            if backend == "pallas":
+                # one device call for all k steps; the cost rows are read
+                # first, in the slot order the per-step loop reads them
+                tcosts = np.stack([self._theta_costs(t)
+                                   for t in range(a, t_end + 1)])
+                C[1:], choice[1:] = minplus_sweep_pallas(C[0], tcosts)
+                sweep.set(device_calls=1)
+            else:
+                for t in range(a, t_end + 1):
+                    tcost = self._theta_costs(t)
+                    cur, ch = minplus_step(C[t - a], tcost, backend=backend)
+                    C[t - a + 1] = cur
+                    choice[t - a + 1] = ch
+                sweep.set(device_calls=0)
             self._choice = choice
         return C
 

@@ -12,11 +12,14 @@ the diagonal). Three implementations, all returning the same values:
     what the golden parity tests pin against);
   * ``minplus_numpy``   — one fancy-indexed Toeplitz build + row-min
     reduction; the default CPU path;
-  * ``minplus_pallas``  — a Pallas TPU kernel of the tropical vec-mat
-    product (broadcast add + lane-min reduce on the VPU), padded to the
-    float32 tile grid and tiled over ``ROW_TILE``-row blocks up to
-    ``MAX_P`` padded states. Off-TPU it runs in interpret mode. A
-    lowering or compile failure raises: nothing falls back to NumPy.
+  * ``minplus_sweep_pallas`` — a Pallas TPU kernel of the tropical
+    vec-mat product (broadcast add + lane-min reduce on the VPU), padded
+    to the float32 tile grid and tiled over ``ROW_TILE``-row blocks up to
+    ``MAX_P`` padded states, run for every step of a DP sweep inside one
+    jitted device loop: one launch and one read for the whole sweep.
+    ``minplus_pallas`` is its one-step case. Off-TPU it runs in interpret
+    mode. A lowering or compile failure raises: nothing falls back to
+    NumPy.
 
 Besides the min values every implementation returns the DP ``choice`` array
 (-1 for an unreachable state). Scalar and NumPy share the exact contract —
@@ -119,16 +122,24 @@ MAX_P = 8192
 #: block-index constant pinned to int32 (a Python 0 traces as int64 under
 #: an x64 scope, which Mosaic cannot lower)
 _ZERO = np.int32(0)
-_pallas_minplus = None                  # lazily created jit
+#: +inf surrogate of the float32 operands, safe under one add
+_BIG = np.float32(3.4e38 / 4)
+#: float32 elements of the host's choice temporary per block of steps
+_CHOICE_BLOCK = 1 << 22
+_pallas_sweep = None                    # lazily created jit
 
 
-def _get_pallas_minplus():
-    """jit: cur[u] = min_v A[u, v] + b[v] on padded (P, P)/(1, P) operands,
-    tiled over ``ROW_TILE``-row blocks of A."""
-    global _pallas_minplus
-    if _pallas_minplus is None:
+def _get_pallas_sweep():
+    """jit of a whole sweep: ``minplus_sweep(prev0, costs, k)`` runs ``k``
+    steps of the ``minplus`` kernel in one device loop, from a (P,) start
+    row over (K_pad, P) cost rows. One step is the product cur[u] =
+    min_v A[u, v] + b[v] on padded (P, P)/(1, P) operands, tiled over
+    ``ROW_TILE``-row blocks of A."""
+    global _pallas_sweep
+    if _pallas_sweep is None:
         import jax
         import jax.numpy as jnp
+        from jax import lax
         from jax.experimental import pallas as pl
 
         def kernel(a_ref, b_ref, o_ref):
@@ -148,53 +159,102 @@ def _get_pallas_minplus():
                 name="minplus",
             )(A, b)
 
-        _pallas_minplus = jax.jit(minplus_pallas_step,
-                                  static_argnames="interpret")
-    return _pallas_minplus
+        def toeplitz(prev):
+            """A[u, v] = prev[u - v], _BIG above the diagonal, by layout
+            alone: row u of the (P, 2P) reshape of z = [_BIG]*(P-1) + prev
+            repeated is z shifted by u, so A[u, v] = z[u - v + P - 1] (a
+            gather of the same entries costs ~0.13 ms a step on a v5e)."""
+            P = prev.shape[0]
+            z = jnp.concatenate([jnp.full(P - 1, _BIG, jnp.float32), prev])
+            rows = jnp.broadcast_to(z, (P + 1, 2 * P - 1)).reshape(-1)
+            return rows[:2 * P * P].reshape(P, 2 * P)[:, P - 1::-1]
+
+        def minplus_sweep(prev0, costs, k, interpret):
+            # prev0 (P,) and costs (K_pad, P) are clamped float32 rows;
+            # row i of the result is step i's output, rows >= k stay _BIG
+            def body(i, carry):
+                prev, out = carry
+                cur = minplus_pallas_step(
+                    toeplitz(prev), lax.dynamic_slice_in_dim(costs, i, 1),
+                    interpret)
+                out = lax.dynamic_update_slice_in_dim(out, cur, i, 0)
+                return jnp.minimum(cur[0], _BIG), out
+
+            out = jnp.full(costs.shape, _BIG, jnp.float32)
+            return lax.fori_loop(np.int32(0), k, body, (prev0, out))[1]
+
+        _pallas_sweep = jax.jit(minplus_sweep, static_argnames="interpret")
+    return _pallas_sweep
 
 
-def minplus_pallas(
-    prev: np.ndarray, tcost: np.ndarray, interpret: Optional[bool] = None
+def minplus_sweep_pallas(
+    prev0: np.ndarray, tcosts: np.ndarray, interpret: Optional[bool] = None
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Tropical vec-mat product on TPU (float32 accumulation).
+    """k DP steps on TPU in one device call (float32 accumulation).
 
-    The Toeplitz operand is built host-side (O(Q^2), tiny); the kernel does
-    the broadcast-add + min-reduce. Rows/cols are padded to the 128-lane
-    tile; padding is +inf-neutral (inf + inf = inf never wins a min). The
-    call runs under a ``device.launch`` span and the read of its output
-    under a ``device.sync`` span, both with the site ``minplus``.
-    Raises ``ValueError`` when the padded width would exceed ``MAX_P``."""
+    ``prev0`` is the (Q+1,) start row and ``tcosts`` the (k, Q+1) theta
+    costs of the k slots; returns ``best`` and ``choice``, both (k, Q+1):
+    row i is the output of step i, fed the output of step i-1 (``prev0``
+    for i = 0). The rows are clamped to a float32 +inf surrogate and
+    padded to the 128-lane tile (padding is +inf-neutral: inf + inf = inf
+    never wins a min) and the cost rows to the next power of two >= k, so
+    one compile serves every k of a bucket. On the device a loop of k
+    steps rebuilds each step's Toeplitz operand from the carried row and
+    runs the ``minplus`` kernel on it. The call runs under one
+    ``device.launch`` span and the read of all k rows under one
+    ``device.sync`` span, both with the site ``minplus_sweep``.
+
+    The carried row is the step's float32 output clamped as the host
+    clamps it, so every step sees the operands that k calls of one step
+    would: the result is bit-identical to feeding the k = 1 sweep its own
+    output k times. Raises ``ValueError`` when the padded width would
+    exceed ``MAX_P``."""
     import jax
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    Q1 = prev.size
+    k, Q1 = tcosts.shape
     P = max(ROW_TILE, int(np.ceil(Q1 / ROW_TILE)) * ROW_TILE)
     if P > MAX_P:
         raise ValueError(
             f"minplus_pallas: {Q1} DP states pad to {P} > MAX_P={MAX_P}; "
             "use the numpy min-plus backend for this quanta count"
         )
-    big = np.float32(3.4e38 / 4)  # inf-surrogate safe under one add
+    K_pad = 1 << (k - 1).bit_length()
+    row0 = np.full(P, _BIG, dtype=np.float32)
+    row0[:Q1] = np.minimum(prev0, _BIG)
+    costs = np.full((K_pad, P), _BIG, dtype=np.float32)
+    costs[:k, :Q1] = np.minimum(tcosts, _BIG)
+    fn = _get_pallas_sweep()
+    with _trace.launch("minplus_sweep"):
+        out = fn(row0, costs, np.int32(k), interpret=interpret)
+    cur32 = _trace.device_get(out, "minplus_sweep")[:k, :Q1]
+    best = np.where(cur32 >= _BIG, _INF, cur32.astype(np.float64))
+    # backtracking pointers recovered host-side from the same float32
+    # operands (standard for DP kernels: the device computes values, not
+    # argmins): vals32[i, u, v] = prev_i[u - v] + cost_i[v], in blocks of
+    # steps that keep the (steps, Q+1, Q+1) temporary bounded
+    prevs = np.empty((k, Q1), dtype=np.float32)
+    prevs[0] = row0[:Q1]
+    prevs[1:] = np.minimum(cur32[:-1], _BIG)
     idx = np.arange(Q1)
     diff = idx[:, None] - idx[None, :]
-    A = np.full((P, P), big, dtype=np.float32)
-    A[:Q1, :Q1] = np.where(
-        diff >= 0, np.minimum(prev, big)[np.abs(diff)], big
-    )
-    b = np.full((1, P), big, dtype=np.float32)
-    b[0, :Q1] = np.minimum(tcost, big)
-    fn = _get_pallas_minplus()
-    with _trace.launch("minplus"):
-        out = fn(A, b, interpret=interpret)
-    cur32 = _trace.device_get(out, "minplus")[0, :Q1]
-    best = np.where(cur32 >= big, _INF, cur32.astype(np.float64))
-    # backtracking pointers recovered host-side from the same operands
-    # (standard for DP kernels: the device computes values, not argmins)
-    vals32 = A[:Q1, :Q1] + b[0, :Q1][None, :]
-    choice = np.argmin(vals32, axis=1).astype(np.int64)
+    choice = np.empty((k, Q1), dtype=np.int64)
+    block = max(1, _CHOICE_BLOCK // (Q1 * Q1))
+    for s in range(0, k, block):
+        vals32 = (np.where(diff >= 0, prevs[s:s + block, np.abs(diff)], _BIG)
+                  + costs[s:min(s + block, k), None, :Q1])
+        choice[s:s + block] = np.argmin(vals32, axis=2)
     choice[~np.isfinite(best)] = -1
     return best, choice
+
+
+def minplus_pallas(
+    prev: np.ndarray, tcost: np.ndarray, interpret: Optional[bool] = None
+) -> Tuple[np.ndarray, np.ndarray]:
+    """One DP step on TPU: the k = 1 case of ``minplus_sweep_pallas``."""
+    best, choice = minplus_sweep_pallas(prev, tcost[None, :], interpret)
+    return best[0], choice[0]
 
 
 # --------------------------------------------------------------- dispatch

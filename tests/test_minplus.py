@@ -1,6 +1,7 @@
 """Property tests for the min-plus (tropical) DP step kernels: the NumPy
 and Pallas implementations must agree with the scalar reference on random
-instances, including +inf (unreachable-state) patterns."""
+instances, including +inf (unreachable-state) patterns; the fused Pallas
+sweep must equal its single step repeated, bit for bit."""
 import math
 
 import numpy as np
@@ -14,6 +15,7 @@ from repro.kernels.minplus import (
     minplus_pallas,
     minplus_scalar,
     minplus_step,
+    minplus_sweep_pallas,
 )
 
 
@@ -77,6 +79,119 @@ def test_pallas_rejects_width_above_max():
     n = MAX_P + 1
     with pytest.raises(ValueError, match="MAX_P"):
         minplus_pallas(np.zeros(n), np.zeros(n), interpret=True)
+
+
+def _random_sweep(rng, k, n, inf_frac=0.2, start="random"):
+    """A start row and k theta-cost rows as the DP feeds them."""
+    prev = np.full(n, np.inf)
+    if start == "random":
+        prev, _ = _random_instance(rng, n, inf_frac)
+    elif start == "dp":
+        prev[0] = 0.0
+    tcosts = rng.uniform(0.0, 1e6, (k, n))
+    tcosts[rng.random((k, n)) < inf_frac] = np.inf
+    tcosts[:, 0] = 0.0
+    return prev, tcosts
+
+
+@pytest.mark.parametrize("k,n,inf_frac,start", [
+    (1, 17, 0.2, "random"),
+    (2, 17, 0.5, "dp"),
+    (5, 17, 0.2, "random"),         # 5 steps in a bucket of 8
+    (17, 33, 0.3, "dp"),            # 17 steps in a bucket of 32
+    (64, 17, 0.2, "dp"),            # the benchmark's W = 64, Q = 16
+    (3, 130, 0.4, "random"),        # two row tiles
+    (9, 17, 0.2, "unreachable"),    # no state reachable at the start
+    (4, 9, 1.0, "dp"),              # every level but v = 0 infeasible
+])
+def test_sweep_matches_repeated_single_steps(k, n, inf_frac, start):
+    """The fused sweep equals k calls of one step, each fed the previous
+    step's output: ``best`` and ``choice`` bit for bit."""
+    rng = np.random.default_rng(1000 * k + n)
+    prev, tcosts = _random_sweep(rng, k, n, inf_frac, start)
+    best, choice = minplus_sweep_pallas(prev, tcosts, interpret=True)
+    assert best.shape == choice.shape == (k, n)
+    row = prev
+    for i in range(k):
+        b, ch = minplus_pallas(row, tcosts[i], interpret=True)
+        np.testing.assert_array_equal(best[i], b)
+        np.testing.assert_array_equal(choice[i], ch)
+        row = b
+    if start == "unreachable":
+        assert np.isinf(best).all() and (choice == -1).all()
+
+
+def test_sweep_choice_in_blocks_matches_one_block(monkeypatch):
+    """The host rebuilds ``choice`` in blocks of steps (bounded memory at
+    large Q): blocks of 3 over 17 steps, a ragged last block included,
+    give the table of one block."""
+    from repro.kernels import minplus
+
+    rng = np.random.default_rng(11)
+    prev, tcosts = _random_sweep(rng, 17, 17, 0.3, "dp")
+    best, choice = minplus_sweep_pallas(prev, tcosts, interpret=True)
+    monkeypatch.setattr(minplus, "_CHOICE_BLOCK", 3 * 17 * 17)
+    best3, choice3 = minplus_sweep_pallas(prev, tcosts, interpret=True)
+    np.testing.assert_array_equal(best3, best)
+    np.testing.assert_array_equal(choice3, choice)
+
+
+def test_sweep_matches_numpy_steps_within_float32():
+    """Against the float64 NumPy step chained k times: the same reachable
+    states and values within float32 accumulation."""
+    rng = np.random.default_rng(7)
+    prev, tcosts = _random_sweep(rng, 12, 17, 0.2, "dp")
+    tcosts[:, 1:] /= 1e4
+    best, choice = minplus_sweep_pallas(prev, tcosts, interpret=True)
+    row = prev
+    for i in range(len(tcosts)):
+        row, ch = minplus_numpy(row, tcosts[i])
+        finite = np.isfinite(row)
+        assert (np.isfinite(best[i]) == finite).all()
+        np.testing.assert_allclose(best[i][finite], row[finite],
+                                   rtol=2e-6, atol=2e-4)
+        assert ((choice[i] < 0) == (ch < 0)).all()
+
+
+def test_pallas_solve_prefix_matches_per_step_loop():
+    """``WorkloadDP.solve_prefix`` on the pallas backend (one fused sweep)
+    gives the C and choice tables of a per-step loop of single Pallas
+    steps over the same memoized theta costs."""
+    from repro.core import (
+        SubproblemConfig, WorkloadConfig, estimate_price_params,
+        make_cluster, synthetic_jobs,
+    )
+    from repro.core.dp import WorkloadDP
+    from repro.core.pricing import PriceTable
+
+    H, T = 6, 10
+    jobs = synthetic_jobs(WorkloadConfig(num_jobs=4, horizon=T, seed=3,
+                                         batch=(20, 100),
+                                         workload_scale=0.05))
+    cluster = make_cluster(H, T)
+    prices = PriceTable(estimate_price_params(jobs, cluster, T), cluster)
+    checked = 0
+    for job in jobs:
+        dp = WorkloadDP(job, cluster, prices, quanta=12,
+                        cfg=SubproblemConfig(minplus_backend="pallas"))
+        C = dp.solve_prefix(T - 1)
+        a, k = job.arrival, T - job.arrival
+        C_loop = np.full_like(C, np.inf)
+        C_loop[0, 0] = 0.0
+        choice_loop = np.full_like(dp._choice, -1)
+        for i in range(k):
+            C_loop[i + 1], choice_loop[i + 1] = minplus_pallas(
+                C_loop[i], dp._theta_costs(a + i), interpret=True)
+        np.testing.assert_array_equal(C, C_loop)
+        np.testing.assert_array_equal(dp._choice, choice_loop)
+        checked += np.isfinite(C[1:]).sum()
+    assert checked, "fixture regression: no reachable DP state"
+
+
+def test_sweep_rejects_width_above_max():
+    n = MAX_P + 1
+    with pytest.raises(ValueError, match="MAX_P"):
+        minplus_sweep_pallas(np.zeros(n), np.zeros((2, n)), interpret=True)
 
 
 def test_all_unreachable():

@@ -364,24 +364,61 @@ def _enclosing(tracer, sp, name):
 
 
 def test_minplus_syncs_match_each_dp_sweeps_steps():
-    """One launch and one sync of the min-plus kernel per DP step, under
-    the dp.sweep that issued them, each a leaf."""
+    """The min-plus kernel's launches and syncs under each dp.sweep match
+    its device calls: one of each for the whole sweep, however many steps
+    it holds, each a leaf with the site minplus_sweep."""
     tracer = Tracer()
     _run_offers(5, 8, 6, 0.05, "compat", tracer=tracer, **JAX_PALLAS)
     sweeps = [sp for sp in tracer.spans if sp.name == "dp.sweep"]
     assert sweeps
+    assert any(sw.attrs["slots"] > 1 for sw in sweeps)
     per_sweep = {sp.index: Counter() for sp in sweeps}
     for sp in tracer.spans:
         if sp.name in ("device.launch", "device.sync"):
             assert "site" in sp.attrs
             assert all(c.parent != sp.index for c in tracer.spans)  # leaf
-            if sp.attrs["site"] == "minplus":
+            assert sp.attrs["site"] != "minplus"    # no per-step round trip
+            if sp.attrs["site"] == "minplus_sweep":
                 owner = _enclosing(tracer, sp, "dp.sweep")
                 assert owner is not None
                 per_sweep[owner.index][sp.name] += 1
     for sw in sweeps:
-        assert per_sweep[sw.index]["device.sync"] == sw.attrs["slots"]
-        assert per_sweep[sw.index]["device.launch"] == sw.attrs["slots"]
+        assert sw.attrs["device_calls"] == 1
+        assert per_sweep[sw.index]["device.sync"] == sw.attrs["device_calls"]
+        assert per_sweep[sw.index]["device.launch"] == sw.attrs["device_calls"]
+
+
+@pytest.mark.parametrize("backend,device_calls", [("pallas", 1),
+                                                  ("numpy", 0)])
+def test_dp_sweep_device_spans_by_backend(backend, device_calls):
+    """One traced dp.sweep: on the pallas backend it holds exactly one
+    device.launch and one device.sync, both of site minplus_sweep, and
+    says device_calls=1; on the numpy backend it holds no device span."""
+    from repro.core.dp import WorkloadDP
+    from repro.core.pricing import PriceTable
+
+    T = 8
+    jobs = synthetic_jobs(WorkloadConfig(num_jobs=2, horizon=T, seed=1,
+                                         batch=(20, 100),
+                                         workload_scale=0.05))
+    cluster = make_cluster(5, T)
+    prices = PriceTable(estimate_price_params(jobs, cluster, T), cluster)
+    dp = WorkloadDP(jobs[0], cluster, prices, quanta=8,
+                    cfg=SubproblemConfig(minplus_backend=backend))
+    tracer = Tracer()
+    with obs_trace.activate(tracer):
+        dp.solve_prefix(T - 1)
+    assert tracer.well_formed()
+    (sweep,) = [sp for sp in tracer.spans if sp.name == "dp.sweep"]
+    assert sweep.attrs["slots"] == T - jobs[0].arrival > 1
+    assert sweep.attrs["device_calls"] == device_calls
+    inside = Counter((sp.name, sp.attrs.get("site"))
+                     for sp in tracer.spans
+                     if sp.name.startswith("device.")
+                     and _enclosing(tracer, sp, "dp.sweep") is sweep)
+    want = {("device.launch", "minplus_sweep"): 1,
+            ("device.sync", "minplus_sweep"): 1} if device_calls else {}
+    assert inside == want
 
 
 def test_device_spans_nest_under_their_layer_span():

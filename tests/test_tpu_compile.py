@@ -66,15 +66,34 @@ def test_pricing_kernel_compiles(one_chip, slots, machines):
 
 @pytest.mark.parametrize("P", [128, 1024, "max"])
 def test_minplus_kernel_compiles(one_chip, P):
-    from repro.kernels.minplus import MAX_P, _get_pallas_minplus
+    """The kernel at each width, as one step runs it: a sweep of one."""
+    from repro.kernels.minplus import MAX_P, _get_pallas_sweep
 
     P = MAX_P if P == "max" else P
-    compiled = _get_pallas_minplus().lower(
-        _spec(one_chip, (P, P), jnp.float32),
+    compiled = _get_pallas_sweep().lower(
+        _spec(one_chip, (P,), jnp.float32),
         _spec(one_chip, (1, P), jnp.float32),
+        _spec(one_chip, (), jnp.int32),
         interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("P", [128, "max"])
+def test_minplus_sweep_compiles(one_chip, P):
+    """The whole DP sweep as one device loop: 64 steps (W = 64) padded to
+    a bucket of 64 cost rows, at the benchmark's width and at MAX_P."""
+    from repro.kernels.minplus import MAX_P, _get_pallas_sweep
+
+    P = MAX_P if P == "max" else P
+    compiled = _get_pallas_sweep().lower(
+        _spec(one_chip, (P,), jnp.float32),
+        _spec(one_chip, (64, P), jnp.float32),
+        _spec(one_chip, (), jnp.int32),
+        interpret=False,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "while" in text
 
 
 @pytest.mark.parametrize("op", [
