@@ -37,9 +37,8 @@ def main(argv=None) -> int:
 
     import jax
     import planted
-    from gen.traffic import load_traffic
     from harness import engine as eng
-    from harness.cells import config_for, find_cell, load_benchmark, traffic_file
+    from harness.cells import load_benchmark, load_cell
 
     if jax.devices()[0].platform != "tpu":
         print("control: no TPU", file=sys.stderr)
@@ -47,10 +46,8 @@ def main(argv=None) -> int:
     from repro.backend import get_backend
     get_backend("jax")
     bench = load_benchmark(REPO)
-    cell = find_cell(bench, args.workload)
-    cfg = config_for(bench, cell, REPO)
-    tr = load_traffic(traffic_file(cell["traffic"]))
-    limits = json.loads((BENCH_DIR / "limits" / f"{cell['name']}.json").read_text())
+    c = load_cell(bench, args.workload, REPO)
+    cell = c.spec
     build = eng.build
     for spec in args.runs:
         name, seeds = spec.split(":")
@@ -67,7 +64,8 @@ def main(argv=None) -> int:
             t0 = time.perf_counter()
             try:
                 result, nums = bench_run.execute(
-                    bench, cell, cfg, tr, limits, seed, args.seconds, False,
+                    bench, cell, c.config, c.traffic, c.limits, seed,
+                    args.seconds, False,
                     t_start=t0)
             finally:
                 eng.build = build

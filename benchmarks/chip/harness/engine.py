@@ -1,5 +1,6 @@
 """Build and drive the system under test: ``SimEngine`` (batched) with the
-``pdors`` policy on a ``RollingWindow`` over ``make_cluster``.
+``pdors`` policy on a ``RollingWindow`` over a ``Cluster`` of the
+configuration's machines.
 
 The policy runs at the backend's own kernel selection; nothing here sets
 a kernel-selection variable. Prices come from the benchmark's frozen
@@ -26,19 +27,33 @@ class Run:
     prices: object
 
 
+def make_cluster_of(cfg: Config, backend: str):
+    """The program's ``Cluster`` of one ``Machine`` a capacity row, as
+    ``make_cluster`` builds it. Where the configuration names a program
+    preset, every class must have the preset's capacities."""
+    from repro.core.cluster import Cluster, Machine, make_cluster
+
+    if cfg.preset is not None:
+        want = dict(make_cluster(1, 1, preset=cfg.preset,
+                                 backend="numpy").machines[0].capacity)
+        for c in cfg.classes:
+            if c.capacity != want:
+                raise ValueError(f"preset {cfg.preset!r} capacities {want} "
+                                 f"!= class {c.name!r} {c.capacity}")
+    machines = [Machine(h, dict(row))
+                for h, row in enumerate(cfg.capacity_rows())]
+    return Cluster(machines=machines, horizon=cfg.window_slots,
+                   backend=backend)
+
+
 def build(cfg: Config, tr: Traffic, seed: int, backend: str,
           seconds: float, tracer=None, on_open=None) -> Run:
-    from repro.core.cluster import make_cluster
     from repro.core.pricing import PriceParams
     from repro.sim import RollingWindow, SimEngine, make_policy
 
-    cluster = make_cluster(cfg.machines, cfg.window_slots, preset=cfg.preset,
-                           backend=backend)
-    if dict(cluster.machines[0].capacity) != cfg.capacity:
-        raise ValueError(f"preset {cfg.preset!r} capacities "
-                         f"{cluster.machines[0].capacity} != {cfg.capacity}")
-    prices = calibrate(calibration_jobs(tr), cfg.capacity,
-                       cfg.machines, cfg.window_slots)
+    cluster = make_cluster_of(cfg, backend)
+    prices = calibrate(calibration_jobs(tr), cfg.capacity_rows(),
+                       cfg.window_slots)
     policy = make_policy("pdors", quanta=cfg.quanta, price_params=PriceParams(
         U=dict(prices.U), L=prices.L, mu=prices.mu))
     window = RollingWindow(cluster)
@@ -73,7 +88,7 @@ def warm_scatter_widths(cluster, tr: Traffic) -> None:
     backend pads each to one of these), on a scratch ledger. One job's
     row in one slot touches at most F worker and F server machines."""
     be = cluster.backend
-    widest = min(cluster.num_machines, 2 * tr.batch[1])
+    widest = min(cluster.num_machines, 2 * tr.max_batch)
     R = len(cluster.resources)
     scratch = be.zeros((cluster.horizon, cluster.num_machines, R))
     width = 1

@@ -11,7 +11,7 @@ def read(ctx):
     rec = ctx["recorder"]
     if launches == 0 or seconds <= 0 or rec.bundle_rows == 0:
         return None
-    flops, nbytes = work.price_bundle(rec.bundle_rows, len(ctx["config"].capacity),
+    flops, nbytes = work.price_bundle(rec.bundle_rows, len(ctx["config"].resources),
                                       rec.bundle_calls)
     pct, _ = work.roofline_pct(flops, nbytes, seconds, ctx["peaks"])
     return pct

@@ -34,15 +34,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import numpy as np
-    from gen.traffic import load_traffic
     from harness import engine as eng, reference
-    from harness.cells import config_for, find_cell, load_benchmark, traffic_file
+    from harness.cells import load_benchmark, load_cell
     from repro.obs.trace import Tracer
 
-    bench = load_benchmark()
-    cell = find_cell(bench, args.workload)
-    cfg = config_for(bench, cell)
-    tr = load_traffic(traffic_file(cell["traffic"]))
+    c = load_cell(load_benchmark(), args.workload)
+    cfg, tr = c.config, c.traffic
     tracer = Tracer()
     t0 = time.perf_counter()
     run = eng.build(cfg, tr, args.seed, args.backend, float("inf"),
@@ -58,12 +55,12 @@ def main(argv=None) -> int:
     for sp in tracer.spans:
         if sp.dur is not None and sp.t0 >= rec.t_open:
             phase[sp.name] += 1
-    cap = np.array([[cfg.capacity[r] for r in sorted(cfg.capacity)]] * cfg.machines)
-    nums = reference.check(run, cap, sorted(cfg.capacity), cfg.quanta,
+    cap = cfg.capacity_array()
+    nums = reference.check(run, cap, cfg.resources, cfg.quanta,
                            used, window.now)
     sizes = [len(b.job_ids) for b in rec.batches]
     out = {
-        "cell": cell["name"], "seed": args.seed,
+        "cell": args.workload, "seed": args.seed,
         "window_slots": [rec.slot_open, rec.slot_close],
         "decisions": rec.decisions(), "admitted": nums.admitted,
         "admission_rate": nums.admitted / max(1, rec.decisions()),

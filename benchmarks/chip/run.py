@@ -141,10 +141,8 @@ def execute(bench: dict, cell: dict, cfg, tr, limits: dict, seed: int,
         tracer, rec.t_open, rec.t_close)
     run.engine = cluster = None             # free the program's state
 
-    resources = sorted(cfg.capacity)
-    cap = np.array([[cfg.capacity[r] for r in resources]] * cfg.machines)
-    nums = reference.check(run, cap, resources, cfg.quanta, final_used,
-                           final_now)
+    nums = reference.check(run, cfg.capacity_array(), cfg.resources,
+                           cfg.quanta, final_used, final_now)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     result = {"correct": None, "attempted": rec.decisions(),
@@ -211,29 +209,25 @@ def execute(bench: dict, cell: dict, cfg, tr, limits: dict, seed: int,
 def main(argv=None) -> int:
     args = parse(argv)
     sys.path.insert(0, str(BENCH_DIR))
-    from gen.traffic import load_traffic
-    from harness.cells import config_for, find_cell, load_benchmark, traffic_file
+    from harness.cells import load_benchmark, load_cell
 
     if not (REPO / "src" / "repro").is_dir():
         fail(f"the system under test is not in this checkout ({REPO / 'src'})")
     bench = load_benchmark(REPO)
-    cell = find_cell(bench, args.workload)
-    cfg = config_for(bench, cell, REPO)
-    tr = load_traffic(traffic_file(cell["traffic"]))
-    limits = json.loads((BENCH_DIR / "limits" / f"{cell['name']}.json").read_text())
+    c = load_cell(bench, args.workload, REPO)
 
     prepare_env()
     import jax
     devices = jax.devices()
     if devices[0].platform != "tpu":
         fail(f"no TPU: JAX's first device is {devices[0].platform!r}")
-    if len(devices) < int(cell["chips"]):
-        fail(f"{len(devices)} chips, the cell asks for {cell['chips']}")
+    if len(devices) < int(c.spec["chips"]):
+        fail(f"{len(devices)} chips, the cell asks for {c.spec['chips']}")
     from repro.backend import get_backend
     get_backend("jax")          # configures the persistent compile cache
 
-    result, _ = execute(bench, cell, cfg, tr, limits, args.seed,
-                        args.seconds, bool(args.trace))
+    result, _ = execute(bench, c.spec, c.config, c.traffic, c.limits,
+                        args.seed, args.seconds, bool(args.trace))
     print(json.dumps(result), flush=True)
     return 0
 

@@ -65,3 +65,32 @@ def test_best_schedule_by_hand():
     # co-located alone needs both slots: 2 levels each at 25
     assert alone.payoff == pytest.approx(u1 - 50.0)
     assert reference.best_schedule(JOB, coloc[:1]) is None
+
+
+def _one_commit_run(machine):
+    """A log of one offer whose job commits 2 workers (6 cpu each) and a
+    server (2 cpu) on ``machine`` in slot 0, admitted."""
+    from types import SimpleNamespace as NS
+    job = NS(job_id=1, arrival=0, epochs=1, num_samples=2, batch_size=2,
+             tau=1.0, grad_size=0.0, gamma=2.0, bw_internal=1.0,
+             bw_external=1.0, worker_demand={"cpu": 6.0, "mem": 1.0},
+             ps_demand={"cpu": 2.0, "mem": 1.0},
+             utility=NS(theta1=1000.0, theta2=0.0, theta3=1.0))
+    rec = NS(start_now=0, slot_open=0, slot_close=1, ops=[
+        ("offer", 0, [job]),
+        ("commit", 0, job, {machine: 2}, {machine: 1}),
+        ("decided", {1: True})])
+    return NS(recorder=rec, arrivals=[(1, 0)],
+              prices=NS(U={"cpu": 10.0, "mem": 10.0}, L=1.0))
+
+
+@pytest.mark.parametrize("machine, over", [(0, 0), (1, 1)])
+def test_commit_is_held_to_its_machine_class(machine, over):
+    """Two classes: machine 0 holds 20 cpu, machine 1 half that. A commit
+    of 14 cpu fits the large class and not the small one."""
+    cap = np.array([[20.0, 100.0], [10.0, 50.0]])
+    nums = reference.check(_one_commit_run(machine), cap, RES, 4,
+                           np.zeros((2, 2, 2)), 0)
+    assert nums.invalid == over
+    assert sum("over capacity" in n for n in nums.notes) == over
+    assert nums.admitted == 1 and nums.unanswered == 0
