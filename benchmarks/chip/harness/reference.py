@@ -20,11 +20,25 @@ numpy, from an empty ledger. Four numbers are compared:
 ``ledger_gap``  the largest difference between the program's device
                 ledger after the window and the reference's replay.
 ``payoff_gap``  over every offer of the window: how far the program's
-                decision falls below the reference's best schedule, in
-                units of that schedule's cost (the payoff is utility
-                minus cost, and on a light cluster the cost is a
-                millionth of the utility, so a share of the utility would
-                hide any pricing fault). A rejection scores payoff 0.
+                decision falls below the reference's best schedule beyond
+                the tie band, in units of that schedule's cost (the
+                payoff is utility minus cost, and on a light cluster the
+                cost is a millionth of the utility, so a share of the
+                utility would hide any pricing fault). A rejection scores
+                payoff 0; where the reference finds no schedule, the gap
+                is read in units of the job's theta_1.
+
+The tie band. The program and the reference both take payoffs within
+1e-12 of each other as equal when they pick a completion slot, and
+Algorithm 4 rounds its LP to whole workers: on a contended fleet, where
+the prices fall towards L (about 1e-25) and a schedule may cost 1e-18,
+the program may pick a schedule whose payoff lies up to about 1e-12
+below the reference's, and that difference divided by such a cost reads
+1e4 or more "cost units". So a shortfall counts only beyond ``TIE_REL`` of the
+larger payoff: ``max(0, B - P - TIE_REL max(|B|, |P|)) / unit``. A
+planted fault loses 70 % of the worst offer's payoff or more, eleven
+decades above the band. The band is no wider than those ties: a shortfall
+that a decision defect makes stays in the gap where it exceeds it.
 
 The reference's schedule is Algorithms 2-3 over Algorithm 4's two
 locality cases, each by a plain rule, in float64 at the prices of the
@@ -40,9 +54,14 @@ Algorithm 4's candidates, split ones included, at the prices of the
 ledger it saw cannot fall far below it; a program that drops the split
 candidates, or prices at a stale ledger, does.
 
-Beside the numbers it reports, not compared, how far the program's
-payoff lies above the co-located case alone (``split_margins``) and how
-many admitted schedules place a slot on more than one machine.
+Beside the numbers it reports, not compared: how far the program's
+payoff lies above the co-located case alone beyond the band, in the
+payoff gap's unit (``split_margins``); how many admitted schedules place
+a slot on more than one machine; the largest shortfall as a share of the
+payoff (``shortfall_rel``); and the offers that the band forgave
+(``tied``), with the largest ratio of the program's schedule cost to the
+reference's among them (``tie_cost_ratio``): at a price near L a tie may
+cost many times the reference's schedule, on a fuller machine.
 """
 from __future__ import annotations
 
@@ -59,6 +78,12 @@ from gen.jobmath import (PlainJob, samples_trained, time_per_sample,
 FIT_TOL = 1e-9
 #: the program's completion tolerance on trained samples (engine)
 WORK_TOL = 1e-6
+#: ties of Algorithm 1's objective, utility minus cost, as a share of the
+#: payoff: the program takes payoffs within 1e-12 as equal, and sound
+#: offers whose LPs were solved to optimality fall short by at most
+#: 3.2e-13 of the payoff; planted faults by 0.70 or more (PERF.md, "How
+#: correct is decided")
+TIE_REL = 1e-12
 
 
 @dataclass
@@ -71,6 +96,9 @@ class Numbers:
     admitted: int = 0
     split_schedules: int = 0       # admitted, some slot on two machines
     split_margins: List[float] = field(default_factory=list)
+    shortfall_rel: float = 0.0     # largest (B - P) / max(|B|, |P|)
+    tied: int = 0                  # offers short by no more than the band
+    tie_cost_ratio: float = 0.0    # largest program / reference cost of those
     notes: List[str] = field(default_factory=list)
 
     def flag(self, msg: str) -> None:
@@ -167,6 +195,13 @@ class Best:
 
     payoff: float
     cost: float
+
+
+def beyond_tie(x: float, y: float) -> float:
+    """``x - y`` less the tie band ``TIE_REL max(|x|, |y|)``, toward 0."""
+    d = x - y
+    band = TIE_REL * max(abs(x), abs(y))
+    return math.copysign(max(0.0, abs(d) - band), d)
 
 
 def _floor_fit(free: np.ndarray, d: np.ndarray) -> np.ndarray:
@@ -418,11 +453,19 @@ def _judge(out: Numbers, batch, admitted: Dict[int, bool],
         else:
             P = 0.0
         B, unit = (full.payoff, full.cost) if full else (0.0, pj.theta[0])
-        gap = (B - P) / unit
+        gap = max(0.0, beyond_tie(B, P)) / unit
+        if B > P:
+            out.shortfall_rel = max(out.shortfall_rel,
+                                    (B - P) / max(abs(B), abs(P)))
+            if gap == 0.0:
+                out.tied += 1
+                if rows and full:
+                    out.tie_cost_ratio = max(out.tie_cost_ratio,
+                                             cost / full.cost)
         if gap > out.payoff_gap:
             out.payoff_gap = gap
             if gap > 1e-6:
                 out.flag(f"job {jid}: payoff {P!r} below the reference's "
                          f"{B!r} by {gap!r} of its cost")
         if rows and coloc is not None:
-            out.split_margins.append((P - coloc.payoff) / coloc.cost)
+            out.split_margins.append(beyond_tie(P, coloc.payoff) / unit)

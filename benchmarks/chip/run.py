@@ -195,11 +195,13 @@ def execute(bench: dict, cell: dict, cfg, tr, limits: dict, seed: int,
           f"({nums.split_schedules} split over machines), "
           f"{nums.offers} scored against the reference, "
           f"{compiles['window']} compiles in the window", file=sys.stderr)
-    if margins:
-        print("chipbench: payoff above the co-located case, in its cost, "
-              "min/median/max over admitted offers: "
-              f"{margins[0]!r} {margins[len(margins) // 2]!r} {margins[-1]!r}",
-              file=sys.stderr)
+    spread = (f"{margins[0]!r} {margins[len(margins) // 2]!r} {margins[-1]!r}"
+              if margins else "none")
+    print("chipbench: beyond the tie band, in the payoff gap's unit: payoff "
+          "above the co-located case, min/median/max over admitted offers, "
+          f"{spread}; {nums.tied} offers short by no more than the band, "
+          f"program/reference cost up to {nums.tie_cost_ratio!r}; largest "
+          f"shortfall {nums.shortfall_rel!r} of the payoff", file=sys.stderr)
     for k, v in checks.items():
         print(f"check {k} {v!r} limit {limits[k]!r}", file=sys.stderr)
     sys.stderr.flush()

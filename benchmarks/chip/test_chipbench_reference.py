@@ -1,8 +1,10 @@
 """The plain reference's search, by hand: the co-located and the split
-placement of each workload level, and the DP over slots (CPU only)."""
+placement of each workload level, the DP over slots, and how an offer's
+shortfall is scored beyond the tie band (CPU only)."""
 import math
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -10,7 +12,7 @@ import pytest
 BENCH_DIR = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH_DIR))
 
-from gen.jobmath import PlainJob  # noqa: E402
+from gen.jobmath import PlainJob, utility  # noqa: E402
 from harness import reference  # noqa: E402
 
 RES = ["cpu", "mem"]
@@ -94,3 +96,74 @@ def test_commit_is_held_to_its_machine_class(machine, over):
     assert nums.invalid == over
     assert sum("over capacity" in n for n in nums.notes) == over
     assert nums.admitted == 1 and nums.unanswered == 0
+
+
+# ------------------------------------------------------- the tie band
+# JOB's schedule in _score: 4 workers (10 cpu each) and 2 servers (5 cpu)
+# on machine 0 in slot 0, at 0.25 a unit of each resource
+COST = 4 * 10 * 0.25 + 2 * 5 * 0.25
+P_ADMIT = utility(JOB, 0) - COST
+
+
+def _score(B, admitted=True, unit=2.0, job=JOB):
+    """The numbers of one offer of ``job`` against a reference whose best
+    schedule pays ``B`` and costs ``unit`` (``B`` None: no schedule)."""
+    price = np.full((1, 1, 2), 0.25)
+    commits = {1: [(0, job, {0: 4}, {0: 2})]} if admitted else {}
+    full = None if B is None else reference.Best(B, unit)
+    out = reference.Numbers()
+    reference._judge(out, [SimpleNamespace(job_id=1)], {1: admitted},
+                     commits, {1: (job, price, full, None)},
+                     reference.Ledger(np.array([[100.0, 100.0]]), RES, 1, 0),
+                     RES)
+    assert out.invalid == 0 and out.unanswered == 0, out.notes
+    return out
+
+
+def test_a_shortfall_of_a_thousand_ulps_is_a_tie():
+    out = _score(P_ADMIT + 1000 * math.ulp(P_ADMIT))
+    assert out.payoff_gap == 0.0
+    assert out.tied == 1 and out.tie_cost_ratio == pytest.approx(COST / 2.0)
+    assert 0 < out.shortfall_rel < reference.TIE_REL
+
+
+def test_a_shortfall_beyond_the_band_is_read_in_cost_units():
+    short = 10 * reference.TIE_REL * P_ADMIT
+    out = _score(P_ADMIT + short, unit=1e-20)
+    band = reference.TIE_REL * (P_ADMIT + short)
+    assert out.payoff_gap == pytest.approx((short - band) / 1e-20, rel=1e-3)
+    assert out.payoff_gap > 0 and out.tied == 0
+
+
+def test_a_rejection_is_scored_against_the_reference_schedule():
+    """The program rejects what the reference admits at payoff 5: the
+    whole payoff, less the band, in units of the reference's cost."""
+    out = _score(5.0, admitted=False, unit=2.0)
+    assert out.payoff_gap == pytest.approx(5.0 * (1 - reference.TIE_REL) / 2.0)
+    assert out.shortfall_rel == 1.0 and out.tied == 0
+
+
+def test_a_loss_where_the_reference_finds_none_is_read_in_theta1():
+    """No reference schedule (B = 0): an admission at a loss of 12.5
+    is read in units of the job's theta_1."""
+    job = PlainJob(**{**JOB.__dict__, "theta": (1.0, 1.0, 0.5)})
+    out = _score(None, job=job)
+    loss = COST - utility(job, 0)
+    assert out.payoff_gap == pytest.approx(loss * (1 - reference.TIE_REL) / 1.0)
+
+
+def test_no_payoff_on_either_side_reads_zero():
+    out = _score(None, admitted=False)
+    assert out.payoff_gap == 0.0 and out.shortfall_rel == 0.0
+    assert out.tied == 0
+
+
+@pytest.mark.parametrize("x, y, want", [
+    (10.0, 10.0, 0.0),
+    (10.0 + 1e-12, 10.0, 0.0),                 # inside 1e-11
+    (10.0 + 1e-10, 10.0, 1e-10 - 1e-11),
+    (10.0, 10.0 + 1e-10, -(1e-10 - 1e-11)),
+])
+def test_beyond_tie(x, y, want, monkeypatch):
+    monkeypatch.setattr(reference, "TIE_REL", 1e-12)
+    assert reference.beyond_tie(x, y) == pytest.approx(want, rel=1e-3, abs=1e-25)
