@@ -597,8 +597,9 @@ class SolvePlan:
                     work.append((p, X))
                     keys.append(key)
             rsp.set(rounded=len(work))
-            with _trace.span("plan.finish", candidates=len(work)):
-                self._finish_batched(work, keys, memo)
+            with _trace.span("plan.finish", candidates=len(work)) as fsp:
+                rows, cells = self._finish_batched(work, keys, memo)
+                fsp.set(head_rows=rows, head_cells=cells)
 
     def _g_delta(self, p: _Pending) -> float:
         """G_delta for one candidate (Theorems 3-4) — the branch
@@ -612,41 +613,30 @@ class SolvePlan:
         return g_delta_packing(cfg.delta, max(p.w2, 1e-6),
                                num_packing_rows=len(p.cand.b_ub) - 1)
 
-    def _aux_stacked(self, kind: str, F_rows: np.ndarray) -> tuple:
-        """Stacked-slot head-room operands: the demand-derived components
-        of ``PriceSnapshot.head_aux`` (shared — demands don't vary by
-        slot) combined with per-candidate SLOT free matrices ``F_rows``
-        ((C, H, R)).  Each candidate's cells are the exact per-slot aux
-        values (same gather + the same ``+ 1e-9`` shift), so
-        ``_headroom_from_aux`` over the stack is bit-identical to
-        per-slot ``_headroom_all`` calls."""
-        snap0 = next(iter(self.snaps.values()))
-        pos, dpos, _fp, wdp, sdp, wdn, sdn, _fn = snap0.head_aux(kind)
-        nonpos = ~pos
-        fpos = F_rows[:, :, pos] + 1e-9
-        fnon = (F_rows[:, :, nonpos] + 1e-9) if nonpos.any() else None
-        return (pos, dpos, fpos, wdp, sdp, wdn, sdn, fnon)
-
     def _finish_batched(
         self,
         work: List[Tuple[_Pending, np.ndarray]],
         keys: List[Tuple[int, int]],
         memo: Dict[Tuple[int, int], Optional[ThetaResult]],
-    ) -> None:
+    ) -> Tuple[int, int]:
         """The rng-free tail of ``_external_finish`` over every candidate
         in ONE stacked pass: rounding feasibility for all candidates of
         all subset sizes and slots together (machine-padded — padding is
         neutral because the padded packing cells evaluate to 0 and
         ``pack_v`` is clamped at 0 anyway, and padded worker cells add
-        exact zeros to the integer-exact sums), head-room rows from
-        per-candidate stacked slot operands (``_aux_stacked``), and the
+        exact zeros to the integer-exact sums), clip detection on the
+        loaded cells only, head-room rows from per-slot zero-load rows
+        with the loaded cells recomputed (``_headroom_rows``), and the
         cover/ratio prefix fills over the whole candidate set with
         per-candidate price orders gathered row-wise.  Only candidates
         whose clip phase actually fires (rare) fall back to the scalar
         ``_repair``.  Results are bit-identical to the per-candidate
-        finish — covered by the plan-vs-loop parity tests."""
+        finish — covered by the plan-vs-loop parity tests.  Returns the
+        head-room work done, (zero-load slot rows, loaded cells), summed
+        over both passes."""
+        head_rows = head_cells = 0
         if not work:
-            return
+            return head_rows, head_cells
         cfg, job = self.cfg, self.job
         S = cfg.rounding_rounds
         batch_cap = float(job.batch_size)
@@ -770,21 +760,23 @@ class SolvePlan:
 
         # ---- repair (infeasible roundings), one stacked pass -----------
         # the whole greedy repair collapses to: clip detection (batched
-        # over every candidate of every slot at once), head-room rows
-        # (stacked slot operands), and the closed-form prefix fill; only
-        # candidates whose clip phase actually fires (rare) fall back to
-        # the scalar ``_repair``, which re-derives everything after
-        # clipping
+        # over the loaded cells of every candidate of every slot at
+        # once; an empty machine cannot clip), head-room rows (per-slot
+        # rows, loaded cells recomputed), and the closed-form prefix
+        # fill; only candidates whose clip phase actually fires (rare)
+        # fall back to the scalar ``_repair``, which re-derives
+        # everything after clipping
         need_repair = np.flatnonzero(~rfeas)
         if need_repair.size:
             ti = need_repair
             Wst = Wall[ti].copy()                        # (C, H)
             Sst = Sall[ti].copy()
-            Fr = F[si[ti]]                               # (C, H, R)
-            need_mat = (Wst[:, :, None] * snap0.wdem
-                        + Sst[:, :, None] * snap0.sdem)  # (C, H, R)
-            okrow = (need_mat <= Fr + 1e-9).all(axis=2)
-            clip = (((Wst > 0) | (Sst > 0)) & ~okrow).any(axis=1)
+            lc, lh = np.nonzero((Wst > 0) | (Sst > 0))  # loaded cells
+            need_mat = (Wst[lc, lh][:, None] * snap0.wdem
+                        + Sst[lc, lh][:, None] * snap0.sdem)  # (cells, R)
+            okrow = (need_mat <= F[si[ti][lc], lh] + 1e-9).all(axis=1)
+            clip = np.zeros(ti.size, dtype=bool)
+            clip[lc[~okrow]] = True
             for c in np.flatnonzero(clip):
                 i = int(ti[c])
                 snap = self.snaps[work[i][0].t]
@@ -798,9 +790,11 @@ class SolvePlan:
                 wsum1 = Wc.sum(axis=1)
                 need = np.ceil(W1c - wsum1).astype(np.int64)
                 budget = (job.batch_size - wsum1).astype(np.int64)
-                heads = _headroom_from_aux(
-                    self._aux_stacked("w", F[si[idx]]), "w", Wc, Sc
+                heads, rows, cells = _headroom_rows(
+                    snap0.head_aux("w"), "w", F, si[idx], Wc, Sc
                 )
+                head_rows += rows
+                head_cells += cells
                 X = np.minimum(need, budget)
                 order = WO[si[idx]]                      # (C, H) per-slot
                 hv = np.minimum(np.take_along_axis(heads, order, 1),
@@ -839,9 +833,11 @@ class SolvePlan:
             if todo.size:
                 idx = alive[todo]
                 Wc, Sc, needc = Wst[todo], Sst[todo], need[todo]
-                heads = _headroom_from_aux(
-                    self._aux_stacked("s", F[si[idx]]), "s", Wc, Sc
+                heads, rows, cells = _headroom_rows(
+                    snap0.head_aux("s"), "s", F, si[idx], Wc, Sc
                 )
+                head_rows += rows
+                head_cells += cells
                 order = SO[si[idx]]
                 hv = np.minimum(np.take_along_axis(heads, order, 1),
                                 needc[:, None])
@@ -873,6 +869,42 @@ class SolvePlan:
             cands = [c for c in (p.internal, ext) if c is not None]
             memo[keys[i]] = (min(cands, key=lambda r: r.cost)
                              if cands else None)
+        return head_rows, head_cells
+
+
+def _headroom_rows(aux: tuple, kind: str, F: np.ndarray, slots: np.ndarray,
+                   W: np.ndarray, S: np.ndarray) -> Tuple[np.ndarray, int, int]:
+    """Head-room rows ((C, H)) for candidates at loads ``(W, S)``, each
+    on the free matrix of its slot, ``F[slots[c]]`` (``F`` stacks the
+    slot free matrices, (U, H, R)); ``aux`` is a snapshot's ``head_aux
+    (kind)``, whose demand operands all slots of a job share.  Returns
+    the rows, the number of zero-load slot rows computed and the number
+    of loaded cells recomputed.
+
+    A machine a candidate leaves empty has the head-room of its slot's
+    zero-load row, so that row is computed once per slot the candidates
+    use and gathered; only the loaded cells are recomputed, on their own
+    gathered free rows.  ``_headroom_from_aux`` is elementwise over the
+    cells and every cell gets the same operands (the slot's free row with
+    the same ``+ 1e-9`` shift), so the result is bit-identical to one
+    dense call over the (C, H, R) stack of ``F[slots]``."""
+    pos, dpos, _fp, wdp, sdp, wdn, sdn, _fn = aux
+    nonpos = ~pos
+    has_non = bool(nonpos.any())
+
+    def shifted(Fx: np.ndarray) -> tuple:
+        fnon = (Fx[..., nonpos] + 1e-9) if has_non else None
+        return (pos, dpos, Fx[..., pos] + 1e-9, wdp, sdp, wdn, sdn, fnon)
+
+    used, inv = np.unique(slots, return_inverse=True)
+    zero = np.zeros((used.size, F.shape[1]), dtype=np.int64)
+    heads = _headroom_from_aux(shifted(F[used]), kind, zero, zero)[inv]
+    c, h = np.nonzero((W != 0) | (S != 0))
+    if c.size:
+        heads[c, h] = _headroom_from_aux(
+            shifted(F[slots[c], h]), kind, W[c, h], S[c, h]
+        )
+    return heads, int(used.size), int(c.size)
 
 
 def solve_plans(plans: List[SolvePlan]) -> None:

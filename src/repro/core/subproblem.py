@@ -923,11 +923,11 @@ def _headroom_all(snap: PriceSnapshot, kind: str, w: np.ndarray,
 def _headroom_from_aux(aux: tuple, kind: str, w: np.ndarray,
                        s: np.ndarray) -> np.ndarray:
     """Head-room core over explicit aux operands.  ``fpos``/``fnon`` may
-    carry a leading candidate axis ((C, H, P) instead of (H, P)) — the
-    plan layer's fused finish stacks per-candidate SLOT free matrices
-    this way, so candidates of different slots batch in one call.  Every
-    op is elementwise over the broadcast cells, so each (candidate,
-    machine) entry is bit-identical to the per-slot call."""
+    carry any leading cell axes in place of (H,) — the plan layer's fused
+    finish passes one zero-load row per slot ((U, H, P)) and the loaded
+    cells' gathered free rows ((N, P)), so candidates of different slots
+    batch in one call.  Every op is elementwise over the broadcast cells,
+    so each cell is bit-identical to the per-slot call."""
     pos, dpos, fpos, wdp, sdp, wdn, sdn, fnon = aux
     P = dpos.shape[1]
     if P == 0:

@@ -136,6 +136,26 @@ def test_offer_span_tree_shape():
         tracer.total_self_s())
 
 
+def test_plan_finish_span_counts_headroom_work():
+    """Each plan.finish span says how much head-room work its finish did:
+    at most two zero-load rows a slot (one a pass) and two loaded cells a
+    candidate machine. On a light cluster the candidates load few of the
+    machines, so the loaded cells are far below candidates x H."""
+    H, T = 64, 10
+    tracer = Tracer()
+    _run_offers(H, T, 8, 0.05, "compat", tracer=tracer)
+    finishes = [sp.attrs for sp in tracer.spans if sp.name == "plan.finish"]
+    assert finishes
+    for at in finishes:
+        cands = at["candidates"]
+        assert 0 <= at["head_rows"] <= 2 * min(cands, T)
+        assert 0 <= at["head_cells"] <= 2 * cands * H
+    cands = sum(at["candidates"] for at in finishes)
+    cells = sum(at["head_cells"] for at in finishes)
+    assert cands > 0 and sum(at["head_rows"] for at in finishes) > 0
+    assert 0 < cells < cands * H / 4
+
+
 # ------------------------------------------- exception well-formedness
 def test_span_tree_well_formed_under_solver_fault():
     tracer = Tracer()

@@ -176,6 +176,84 @@ def test_headroom_all_matches_scalar_oracle():
                 assert got[c, h] == row[h] == ref
 
 
+def _dense_heads(aux, kind, F, slots, W, S):
+    """The dense head-room oracle: one ``_headroom_from_aux`` call over
+    the (C, H, R) stack of each candidate's slot free matrix."""
+    from repro.core.subproblem import _headroom_from_aux
+
+    pos, dpos, _fp, wdp, sdp, wdn, sdn, _fn = aux
+    Fr = F[slots]
+    fnon = (Fr[:, :, ~pos] + 1e-9) if (~pos).any() else None
+    return _headroom_from_aux(
+        (pos, dpos, Fr[:, :, pos] + 1e-9, wdp, sdp, wdn, sdn, fnon),
+        kind, W, S,
+    )
+
+
+@pytest.mark.parametrize("kind", ["w", "s"])
+@pytest.mark.parametrize("case", ["shared_slots", "some_slots", "boundary",
+                                  "guard", "unloaded"])
+def test_headroom_rows_match_dense_stack(kind, case):
+    """Per-slot zero-load rows with the loaded cells recomputed equal the
+    dense (C, H, R) head-room bit for bit: candidates sharing a slot, a
+    pass that uses only some slots, loads at exact capacity boundaries
+    (the one-ulp fix-up), a zero-demand column with negative free cells
+    (the guard) and candidates with no loaded cell."""
+    from repro.core.solve_plan import _headroom_rows
+    from repro.core.subproblem import PriceSnapshot
+
+    H, T, U, C = 9, 6, 4, 12
+    jobs = synthetic_jobs(WorkloadConfig(num_jobs=6, horizon=T, seed=2,
+                                         batch=(50, 200),
+                                         workload_scale=0.2))
+    # gpu is a zero-demand column of both kinds for job 0; for job 1 only
+    # the servers' gpu demand is zero, so the "s" guard reads the workers'
+    # gpu load against the free gpu
+    job = jobs[1] if case == "guard" and kind == "s" else jobs[0]
+    cluster = make_cluster(H, T)
+    prices = PriceTable(estimate_price_params(jobs, cluster, T), cluster)
+    snap = PriceSnapshot(job, cluster, prices, 0)
+    aux = snap.head_aux(kind)
+    rng = np.random.default_rng(["shared_slots", "some_slots", "boundary",
+                                 "guard", "unloaded"].index(case))
+    R = snap.wdem.size
+    F = rng.uniform(0.0, 1.0, (U, H, R)) * snap.free_mat
+    slots = rng.integers(0, U, C)
+    if case == "shared_slots":
+        slots[: C // 2] = 2
+    if case == "some_slots":
+        slots = rng.choice([0, 3], C)
+    loaded = rng.random((C, H)) < (0.0 if case == "unloaded" else 0.3)
+    W = np.where(loaded, rng.integers(0, 5, (C, H)), 0)
+    S = np.where(loaded, rng.integers(0, 3, (C, H)), 0)
+    if case == "boundary":
+        # free = the load grown by k units exactly, less the tolerance
+        k = rng.integers(0, 4, (C, H))
+        dem = snap.wdem if kind == "w" else snap.sdem
+        full = (W[:, :, None] * snap.wdem + S[:, :, None] * snap.sdem
+                + k[:, :, None] * dem) - 1e-9
+        F[slots] = full          # the last candidate of a slot wins
+    if case == "guard":
+        gpu = cluster.resources.index("gpu")
+        assert not aux[0][gpu]   # gpu is a zero-demand column of the kind
+        F[:, ::2, gpu] = -1.0    # negative free gpu on every other machine
+        F[:, 1::2, gpu] = 1.5    # fits one worker's gpu, not two
+    got, rows, cells = _headroom_rows(aux, kind, F, slots, W, S)
+    want = _dense_heads(aux, kind, F, slots, W, S)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    assert rows == np.unique(slots).size
+    assert cells == int(((W != 0) | (S != 0)).sum())
+    if case == "unloaded":
+        assert cells == 0
+        for c in range(C):
+            first = np.flatnonzero(slots == slots[c])[0]
+            assert np.array_equal(got[c], got[first])
+    if case == "guard":
+        assert (got[:, ::2] == 0).all()
+        if kind == "s":
+            assert (got[:, 1::2][W[:, 1::2] >= 2] == 0).all()
+
+
 def test_fused_bundle_batch_matches_per_slot_numpy():
     """The fused (W, H) bundle pass must be bit-identical to W per-slot
     reductions on the numpy backend."""
@@ -240,6 +318,74 @@ def _plan_fixture(seed=3, H=8, T=8, N=10, scale=0.08):
     params = estimate_price_params(jobs, cluster, T)
     prices = PriceTable(params, cluster)
     return jobs, cluster, prices
+
+
+def test_finish_clip_check_matches_scalar_repair(monkeypatch):
+    """The fused finish's clip check reads only loaded cells: a rounding
+    that overloads one of its machines takes the scalar ``_repair``
+    fallback, one that fits (a cover shortfall alone) does not, and every
+    candidate's external result equals the scalar repair and ratio
+    guarantee on the same rounding."""
+    import repro.core.solve_plan as sp
+    from repro.core.subproblem import _ensure_ratio, _repair
+    from repro.core.job import Allocation
+    from repro.core.subproblem import ThetaResult, _alloc_cost
+
+    jobs, cluster, prices = _plan_fixture(seed=6, scale=0.3, H=10, N=12)
+    job = jobs[1]
+    cfg = SubproblemConfig()
+    plan = SolvePlan(job, cluster, prices, cfg, job.arrival,
+                     cluster.horizon - 1, quanta=32).solve()
+    pend = [p for p in plan.pending if p.action == sp._A_LP
+            and plan.lp_results[p.lp_index].status == "optimal"
+            and p.cand.W1 > 2]
+    assert len(pend) >= 4, "fixture regression: too few LP candidates"
+    S, H = cfg.rounding_rounds, cluster.num_machines
+    work, keys, want, clipped = [], [], {}, 0
+    for n, p in enumerate(pend):
+        M = len(p.cand.machines)
+        # one worker and one server on the first machine: they fit, and
+        # the cover falls short ...
+        X0 = np.zeros(2 * M, dtype=np.int64)
+        X0[0] = X0[M] = 1
+        if n % 3 == 1:                           # ... or nothing loaded
+            X0[:] = 0
+        if n % 3 == 2:                           # ... or an overload too
+            X0[0] = 10 * job.batch_size
+            clipped += 1
+        q = sp._Pending(p.t, p.v, sp._A_LP, None, cand=p.cand,
+                        lp_index=p.lp_index, w2=p.w2)
+        work.append((q, np.tile(X0, (S, 1))))
+        keys.append((p.t, p.v))
+        # the scalar tail of _external_finish on the same rounding
+        snap = plan.snaps[p.t]
+        w = np.zeros(H, dtype=np.int64)
+        s = np.zeros(H, dtype=np.int64)
+        w[p.cand.machines], s[p.cand.machines] = X0[:M], X0[M:]
+        w, s = _repair(job, snap, w, s, p.cand.W1)
+        if w is not None:
+            s = _ensure_ratio(job, snap, w, s)
+        ext = None
+        if w is not None and s is not None and int(w.sum()) != 0:
+            alloc = Allocation(
+                workers={int(h): int(w[h]) for h in np.flatnonzero(w > 0)},
+                ps={int(h): int(s[h]) for h in np.flatnonzero(s > 0)})
+            ext = ThetaResult(cost=_alloc_cost(snap, alloc), alloc=alloc,
+                              mode="external")
+        want[(p.t, p.v)] = ext
+    calls = []
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return _repair(*a, **kw)
+
+    monkeypatch.setattr(sp, "_repair", counted)
+    memo = {}
+    plan._finish_batched(work, keys, memo)
+    assert len(calls) == clipped
+    assert any(v is not None for v in want.values())
+    for k in keys:
+        assert _theta_equal(memo[k], want[k]), k
 
 
 @pytest.mark.parametrize("rng_mode", ["compat", "derived"])
