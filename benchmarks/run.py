@@ -12,18 +12,9 @@ import traceback
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale sweeps")
-    ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: only the scheduler-throughput smoke "
-                         "benchmark (tiny grid, < 60 s)")
     ap.add_argument("--only", default=None,
                     help="comma-separated figure names (e.g. fig6,fig10)")
     args = ap.parse_args()
-
-    if args.smoke:
-        from . import bench_scheduler
-        sys.exit(bench_scheduler.main(
-            ["--smoke", "--out", "BENCH_scheduler_smoke.json"]
-        ))
 
     from . import (
         fig6_machines, fig7_jobs, fig8_oasis, fig9_median_time,

@@ -3,7 +3,7 @@ and every registered public symbol exists in code AND is documented.
 
 Cheap grep-based gate for the equations-to-code map: extracts every
 backtick-quoted repo path (``src/...``, ``scripts/...``, ``tests/...``,
-``benchmarks/...``, ``docs/...``, ``BENCH_*.json``, top-level ``*.md``)
+``benchmarks/...``, ``docs/...``, top-level ``*.md``)
 and every dotted ``repro.foo.bar`` module reference from the markdown
 files under docs/ (plus README.md), and fails listing anything that no
 longer exists — so module renames cannot silently rot the architecture
@@ -27,7 +27,7 @@ ROOT = Path(__file__).resolve().parent.parent
 
 PATH_RE = re.compile(
     r"`((?:src|scripts|tests|benchmarks|examples|docs)/[\w./\-]+"
-    r"|BENCH_[\w.]+\.json|[A-Z][\w\-]*\.md)`"
+    r"|[A-Z][\w\-]*\.md)`"
 )
 MODULE_RE = re.compile(r"`(repro(?:\.\w+)+)`")
 
@@ -66,8 +66,7 @@ PUBLIC_SYMBOLS = {
                                 "checkpoint_every", "refail_rate",
                                 "engine_mode", "admission_latency",
                                 "reshape_cooldown", "ElasticState"],
-    "src/repro/sim/policy.py": ["ResilientPolicy", "use_warm_bundles",
-                                "on_reshape"],
+    "src/repro/sim/policy.py": ["ResilientPolicy", "on_reshape"],
     "src/repro/sim/metrics.py": ["samples_trained", "P2Quantile",
                                  "job_done", "job_closed",
                                  "deadline_hit", "slo_hit"],
@@ -78,7 +77,6 @@ PUBLIC_SYMBOLS = {
     "src/repro/sim/service.py": ["OfferService", "poll", "heartbeat",
                                  "metrics_text", "start_http"],
     "src/repro/backend/__init__.py": ["lp_solver_default"],
-    "benchmarks/bench_scheduler.py": ["repeat-best-of", "--profile"],
     "src/repro/obs/trace.py": ["Tracer", "Span", "phase_table",
                                "total_self_s", "activate", "device_get"],
     "src/repro/obs/metrics.py": ["MetricsRegistry", "Counter", "Gauge",
@@ -122,12 +120,9 @@ def check_symbols(docs: list) -> list:
             continue
         src = path.read_text()
         for sym in symbols:
-            # flags like `repeat-best-of` appear verbatim; identifiers
-            # must be defined (def/class/field/assignment)
+            # a symbol must be defined (def/class/field/assignment)
             ident = re.escape(sym)
-            defined = (
-                "-" in sym and sym in src
-            ) or re.search(
+            defined = re.search(
                 rf"(?:def {ident}\b|class {ident}\b|^\s*{ident}\s*[:=])",
                 src, re.M,
             ) is not None

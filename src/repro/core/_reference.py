@@ -1,16 +1,13 @@
 """Frozen pre-vectorization PD-ORS core (verbatim from the seed commit).
 
-This module is the *measurement baseline and parity oracle* for the
-vectorized scheduling core: the complete pre-PR implementation -- dict-keyed
-ledger, per-element price snapshots, scalar two-phase simplex, pure-Python
-min-plus DP inner loop, unit-at-a-time repair -- concatenated into one
-self-contained module. Nothing here runs on the hot path; it exists so
-
-  * benchmarks/bench_scheduler.py can report an honest "pre-PR core"
-    jobs/sec + latency column and a speedup ratio, and
-  * tests can assert bit-identical admission records, schedules, and total
-    utility between ``run_pdors`` and ``run_pdors_reference`` at fixed
-    seeds (the golden pre/post-vectorization regression).
+This module is the *parity oracle* for the vectorized scheduling core:
+the complete pre-PR implementation -- dict-keyed ledger, per-element price
+snapshots, scalar two-phase simplex, pure-Python min-plus DP inner loop,
+unit-at-a-time repair -- concatenated into one self-contained module.
+Nothing here runs on the hot path; it exists so tests can assert
+bit-identical admission records, schedules, and total utility between
+``run_pdors`` and ``run_pdors_reference`` at fixed seeds (the golden
+pre/post-vectorization regression).
 
 Do not optimize or "clean up" this file -- its value is being frozen.
 Only mechanical edits were made: module docstrings/imports were hoisted

@@ -95,7 +95,7 @@ class Cluster:
         # tensors (commit/release on t, a capacity-mask change, or the row
         # sliding in on advance). A slot whose stamp is unchanged since a
         # SolvePlan was built has bit-identical free/price content, which
-        # is what plan patching and warm bundle reuse key on.
+        # is what plan patching keys on.
         self._slot_versions = np.zeros(self.horizon, dtype=np.int64)
         # counts advance() calls: plan patching is only valid while the
         # window has not slid (relative slot indices keep their meaning)
@@ -347,9 +347,8 @@ class Cluster:
         self.version += 1
         self.advances += 1
         # stamps slide with their row content: index k now refers to the
-        # slot that was k+steps, so a warm-store entry keyed by absolute
-        # slot + stamp stays valid across the slide. Fresh back rows are
-        # stamped with the current version (their zero content is new).
+        # slot that was k+steps. Fresh back rows are stamped with the
+        # current version (their zero content is new).
         k = min(steps, self.horizon)
         if k < self.horizon:
             self._slot_versions[:-k] = self._slot_versions[k:]

@@ -12,7 +12,7 @@ bump the cluster's ledger version, which is what invalidates those
 caches between admissions (the subset-template cache is
 content-addressed and survives them — ``docs/SOLVER.md``). ``repro.core._reference.run_pdors_reference`` is the frozen
 pre-vectorization implementation producing bit-identical decisions —
-``benchmarks/bench_scheduler.py`` measures one against the other.
+the golden tests in ``tests/test_scheduler.py`` hold one against the other.
 """
 from __future__ import annotations
 

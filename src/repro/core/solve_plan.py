@@ -186,7 +186,6 @@ class SolvePlan:
         t_hi: int,
         quanta: int = 32,
         skip: Optional[set] = None,
-        warm: Optional[Dict[int, tuple]] = None,
     ):
         self.job = job
         self.cluster = cluster
@@ -216,7 +215,7 @@ class SolvePlan:
         self.lp_results: Optional[List[LPResult]] = None
         with _trace.span("plan.build", job=int(job.job_id),
                          slots=t_hi - t_lo + 1, quanta=self.quanta) as sp:
-            self._collect(prices, skip or set(), warm=warm)
+            self._collect(prices, skip or set())
             sp.set(n_lp=len(self.lp_built), n_pending=len(self.pending),
                    n_trivial=len(self.trivial))
 
@@ -305,17 +304,10 @@ class SolvePlan:
 
     # ------------------------------------------------------------------
     def _collect(self, prices: PriceTable, skip: set,
-                 ts: Optional[List[int]] = None,
-                 warm: Optional[Dict[int, tuple]] = None) -> None:
+                 ts: Optional[List[int]] = None) -> None:
         """Collect + classify the (slot, level) grid for slots ``ts``
         (default: the plan's full [t_lo, t_hi] range — ``patch`` passes
-        just the dirty subset). ``warm`` maps a slot to a previously
-        computed decision bundle for an identical (ledger row, demand)
-        pair; on the numpy backend each slot's bundle is computed
-        independently of the others (``price_bundle_batch_numpy`` is a
-        per-(t, h) map), so splicing a warm row is bit-identical to
-        recomputing it. The device backend ignores ``warm`` — its fused
-        reduction is one full-horizon dispatch either way."""
+        just the dirty subset)."""
         job, cluster, cfg = self.job, self.cluster, self.cfg
         Q = self.quanta
         if ts is None:
@@ -341,19 +333,13 @@ class SolvePlan:
                 for t in ts:
                     bundles[t] = (wp[t], sp[t], co[t], mw[t], ms[t])
             else:
-                if warm:
-                    bundles.update((t, warm[t]) for t in ts if t in warm)
-                cold = [t for t in ts if t not in bundles]
-                if cold:
-                    price_op = np.stack(
-                        [prices.price_matrix(t) for t in cold])
-                    free_op = np.stack(
-                        [cluster.free_matrix(t) for t in cold])
-                    wp, sp, co, mw, ms = cluster.backend.snapshot_bundle_batch(
-                        price_op, free_op, wdem, sdem, job.gamma,
-                    )
-                    for i, t in enumerate(cold):
-                        bundles[t] = (wp[i], sp[i], co[i], mw[i], ms[i])
+                price_op = np.stack([prices.price_matrix(t) for t in ts])
+                free_op = np.stack([cluster.free_matrix(t) for t in ts])
+                wp, sp, co, mw, ms = cluster.backend.snapshot_bundle_batch(
+                    price_op, free_op, wdem, sdem, job.gamma,
+                )
+                for i, t in enumerate(ts):
+                    bundles[t] = (wp[i], sp[i], co[i], mw[i], ms[i])
             for t in ts:
                 self.slot_versions[t] = cluster.slot_version(t)
                 self.snaps[t] = PriceSnapshot(

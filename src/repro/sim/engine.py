@@ -31,8 +31,8 @@ One slot of simulated time is processed as:
      counts.
 
 The engine owns ALL accounting (progress, completions, utility, metrics);
-policies only decide allocations. That is what makes the per-policy
-numbers in ``BENCH_sim.json`` apples-to-apples.
+policies only decide allocations. That is what makes per-policy numbers
+apples-to-apples.
 
 Crash-consistent recovery
 -------------------------
@@ -705,11 +705,11 @@ class SimEngine:
         updated demand signature. Slot ``t``'s earnings stand (the release
         starts at ``t + 1`` — completion-style, unlike a failure's
         lost-slot release at ``t``). Arrival-driven policies get the
-        reshaped residual as a next-slot re-offer (the warm bundle store
-        sees a NEW signature and must recompute); slot-driven policies get
-        the spec swapped in place — arrival preserved, so the per-slot
-        ordering key fixed at activation stays identical in both engine
-        modes — and re-place the new demands at the next tick."""
+        reshaped residual as a next-slot re-offer at its new demands;
+        slot-driven policies get the spec swapped in place — arrival
+        preserved, so the per-slot ordering key fixed at activation stays
+        identical in both engine modes — and re-place the new demands at
+        the next tick."""
         job_id = js.job.job_id
         residual = self._residual(js, t)
         if residual is None:

@@ -3,11 +3,10 @@
 Per-slot series (utilization per resource, active/queued counts) are
 recorded as the engine runs; per-job outcomes (admission, queueing delay,
 JCT, utility, preemptions) are recorded as their events fire. ``summary()``
-folds both into the flat dict that ``benchmarks/bench_sim.py`` writes to
-``BENCH_sim.json``: JCT p50/p95/mean + CDF, queueing-delay percentiles,
-admission/completion rates, mean utilization, and total realized utility
-(u_i evaluated at the *actual* completion latency, per the engine's
-accounting — never the policy's own estimate).
+folds both into one flat dict: JCT p50/p95/mean + CDF, queueing-delay
+percentiles, admission/completion rates, mean utilization, and total
+realized utility (u_i evaluated at the *actual* completion latency, per
+the engine's accounting — never the policy's own estimate).
 
 Conventions: JCT and utility are measured for completed jobs only;
 ``completion_rate``/``admission_rate`` put the censoring in plain sight.
@@ -289,10 +288,10 @@ class MetricsCollector:
     (``outcome`` creates-or-returns; the engine writes admission, service,
     completion, preemption, and utility fields as events fire), per-slot
     utilization/active/queued series (``record_slot``), and raw event
-    counters (``count``). ``summary()`` is the flat dict that becomes one
-    ``BENCH_sim.json`` row; ``jct_cdf``/``to_json`` serve the figure
-    scripts. Policies never touch this object — identical, engine-owned
-    measurement is what keeps per-policy rows comparable."""
+    counters (``count``). ``summary()`` is one run's flat result dict;
+    ``jct_cdf``/``to_json`` serve the figure scripts. Policies never
+    touch this object — identical, engine-owned measurement is what
+    keeps per-policy rows comparable."""
 
     def __init__(self, resources: List[str], num_machines: int = 0,
                  mode: str = "exact"):

@@ -33,8 +33,8 @@ PD-ORS offers — so replaying a trace, or reordering policy runs, can never
 shift another decision's draws.
 
 Registry: ``@register_policy(name)`` + ``make_policy(name, **kw)`` +
-``available_policies()``. ``benchmarks/bench_sim.py`` and the tests only
-go through the registry.
+``available_policies()``. The engine, the chip benchmark and the tests
+only go through the registry.
 """
 from __future__ import annotations
 
@@ -57,7 +57,7 @@ from ..core.schedule import find_best_schedule
 from ..core.solve_plan import SolvePlan, solve_plans
 from ..core.subproblem import SolverFault, SubproblemConfig
 from ..obs import trace as _trace
-from ..obs.metrics import get_registry, warn_once_event
+from ..obs.metrics import warn_once_event
 from ..obs.pd_gap import PDGapTracker
 from .events import Event, EventKind
 from .window import RollingWindow
@@ -159,11 +159,11 @@ class SchedulingPolicy:
         Eq. (1)/Fact 1), detects completion (progress >= V_i), releases
         remaining rows, realizes utility u_i(actual JCT), applies
         patience departures, and records every metric. Policies are pure
-        deciders: identical accounting is what makes per-policy rows in
-        ``BENCH_sim.json`` comparable. COMPLETION / PREEMPT / DEPARTURE
-        offers are notifications (return value ignored) — policies use
-        them to drop internal state (e.g. held allocations), not to
-        mutate the ledger: the engine has already released the rows.
+        deciders: identical accounting is what makes per-policy results
+        comparable. COMPLETION / PREEMPT / DEPARTURE offers are
+        notifications (return value ignored) — policies use them to drop
+        internal state (e.g. held allocations), not to mutate the ledger:
+        the engine has already released the rows.
         """
         if event.kind == EventKind.ARRIVAL:
             return self.on_arrivals(event, view)
@@ -262,7 +262,6 @@ class PDORSPolicy(SchedulingPolicy):
         quanta: int = 16,
         cfg: Optional[SubproblemConfig] = None,
         rng_mode: str = "derived",
-        use_warm_bundles: bool = True,
     ):
         if rng_mode not in ("derived", "compat"):
             raise ValueError(f"rng_mode must be derived|compat, got {rng_mode!r}")
@@ -270,12 +269,6 @@ class PDORSPolicy(SchedulingPolicy):
         self.quanta = quanta
         self.base_cfg = cfg or SubproblemConfig()
         self.rng_mode = rng_mode
-        # warm-vs-cold parity switch: False disables the warm bundle store
-        # entirely (every plan rebuilds its bundles from the live ledger).
-        # Decisions MUST be bit-identical either way — the warm store is a
-        # cache, never an approximation — and the elastic property suite
-        # asserts exactly that under signature churn.
-        self.use_warm_bundles = bool(use_warm_bundles)
         self.attempts: Dict[int, int] = {}
 
     def bind(self, view: RollingWindow, seed: int) -> None:
@@ -285,70 +278,6 @@ class PDORSPolicy(SchedulingPolicy):
         # per offer; decisions never read it. Rebinding (a fresh window)
         # restarts the accumulators with the fresh price table.
         self.pd_gap = PDGapTracker(self.prices)
-        # warm decision-bundle store for re-offers: (absolute slot, the
-        # slot's ledger-version stamp, demand signature) -> the fused
-        # (wprice, sprice, coloc, max_w, max_s) bundle row. A requeued or
-        # preempt-re-offered job has the same demand vectors as its
-        # original offer, so every slot whose ledger row is untouched
-        # since then reuses the already-computed bundle bit-for-bit
-        # (numpy backend only — the device bundle pass is one fused
-        # dispatch either way and its floats are tolerance-, not
-        # bit-stable).
-        self._warm_bundles: Dict[tuple, tuple] = {}
-        self._warm_now = 0
-
-    # -- warm bundle store ---------------------------------------------
-    def _bundle_sig(self, view: RollingWindow, job: JobSpec) -> tuple:
-        wdem, sdem = view.cluster.demand_vectors(job)
-        return (wdem.tobytes(), sdem.tobytes(), float(job.gamma))
-
-    def _warm_for(self, view: RollingWindow,
-                  rel: JobSpec) -> Optional[Dict[int, tuple]]:
-        """Collect warm bundles for one job's plan slots. Keys carry the
-        slot's version stamp, so a stale row can never hit."""
-        cl = view.cluster
-        if cl.backend.is_device or not self.use_warm_bundles:
-            return None
-        if view.now != self._warm_now:
-            self._warm_bundles = {
-                k: v for k, v in self._warm_bundles.items()
-                if k[0] >= view.now
-            }
-            self._warm_now = view.now
-        sig = self._bundle_sig(view, rel)
-        warm = {
-            t: hit
-            for t in range(rel.arrival, view.lookahead)
-            if (hit := self._warm_bundles.get(
-                (view.now + t, cl.slot_version(t), sig))) is not None
-        }
-        if warm:
-            get_registry().counter(
-                "repro_warm_bundle_hits_total",
-                "plan bundle rows reused from the warm store",
-            ).inc(len(warm))
-        return warm or None
-
-    def _harvest_bundles(self, view: RollingWindow, rel: JobSpec,
-                         plan: SolvePlan) -> None:
-        """Store the freshly built plan's bundle rows (called right after
-        the build, before any admission can mutate the ledger)."""
-        cl = view.cluster
-        if cl.backend.is_device or not self.use_warm_bundles:
-            return
-        sig = self._bundle_sig(view, rel)
-        for t, snap in plan.snaps.items():
-            self._warm_bundles[(view.now + t, cl.slot_version(t), sig)] = (
-                snap.wprice, snap.sprice, snap.coloc,
-                snap.max_w, snap.max_s,
-            )
-        if len(self._warm_bundles) > 16384:
-            # bounded store: evict the oldest absolute slots first
-            drop = sorted({k[0] for k in self._warm_bundles})
-            cut = drop[len(drop) // 2]
-            self._warm_bundles = {
-                k: v for k, v in self._warm_bundles.items() if k[0] >= cut
-            }
 
     def pd_gap_stats(self) -> Optional[Dict[str, object]]:
         """Primal-dual telemetry snapshot (engine folds it into the
@@ -439,9 +368,7 @@ class PDORSPolicy(SchedulingPolicy):
                     plan = SolvePlan(rel, view.cluster, self.prices,
                                      cfg, rel.arrival,
                                      view.lookahead - 1,
-                                     quanta=self.quanta,
-                                     warm=self._warm_for(view, rel))
-                    self._harvest_bundles(view, rel, plan)
+                                     quanta=self.quanta)
                 else:
                     plan = None
                 plans[job.job_id] = plan

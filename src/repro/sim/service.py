@@ -25,8 +25,7 @@ to the simulator, not a wire format.
 
 SLO accounting: per-offer admission latency (submit -> decision) feeds
 streaming P-squared p50/p99 estimators (``sim.metrics.P2Quantile``) and
-is published as gauges in the registry; ``benchmarks/bench_sim.py``
-records the same columns for the service-latency benchmark rows.
+is published as gauges in the registry.
 """
 from __future__ import annotations
 

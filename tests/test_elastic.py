@@ -9,9 +9,6 @@ The invariants, asserted across policies and both engine modes:
 * under reshape storms the ledger is never oversubscribed
   (``check_ledger`` is always on — a violation raises), and batched vs
   per-event engines stay bit-identical;
-* warm-vs-cold ``SolvePlan`` decisions are identical under signature
-  churn, and the warm bundle store can never splice a stale bundle after
-  a mid-run demand change (the satellite regression test);
 * ``SimEngine.recover()`` replays in-flight reshapes bit-identically.
 """
 from __future__ import annotations
@@ -38,7 +35,6 @@ from strategies import (
     ALL_POLICIES,
     QUALITY_KEYS,
     assert_equivalent,
-    assert_reports_identical,
     make_trace,
     policies,
     reshape_storm,
@@ -194,52 +190,6 @@ def test_elastic_jax_backend():
     pytest.importorskip("jax")
     assert_equivalent("fifo", 2, trace_cfg=reshape_storm(2, num_jobs=30),
                       num_jobs=30, backend="jax")
-
-
-# ------------------------------------------------ warm-vs-cold parity
-def test_warm_vs_cold_decision_parity_under_signature_churn():
-    """use_warm_bundles=False rebuilds every bundle from the live ledger;
-    decisions, ledger, and journal must be bit-identical to the warm run
-    even while reshapes churn demand signatures mid-stream."""
-    storm = reshape_storm(31)
-    r1, e1 = run_sim("pdors", "batched", 31, trace_cfg=storm,
-                     policy_kwargs={"use_warm_bundles": True})
-    r2, e2 = run_sim("pdors", "batched", 31, trace_cfg=storm,
-                     policy_kwargs={"use_warm_bundles": False})
-    assert_reports_identical(r1, e1, r2, e2)
-    assert e1.policy.use_warm_bundles and not e2.policy.use_warm_bundles
-    assert e2.policy._warm_bundles == {}  # cold run never stored a bundle
-
-
-def test_warm_store_misses_on_demand_signature_change():
-    """Satellite regression: the warm store keys on (abs slot, slot
-    version, demand signature). A mid-run demand-level change leaves the
-    slot versions untouched — ONLY the signature separates the reshaped
-    job from its old self, so a signature mismatch must miss, never
-    splice the stale bundle."""
-    cfg = make_trace(3)
-    cl = make_cluster(6, 12)
-    win = RollingWindow(cl)
-    pol = make_policy("pdors", price_params=calibrate_prices(cfg, cl, n=16),
-                      quanta=8)
-    pol.bind(win, seed=3)
-    job = _elastic_job()
-    rel = win.rel_job(job)
-    sig = pol._bundle_sig(win, rel)
-    # harvest a fake bundle row for every plan slot at the CURRENT slot
-    # versions (exactly what _harvest_bundles records after a real build)
-    for t in range(rel.arrival, win.lookahead):
-        pol._warm_bundles[(win.now + t, cl.slot_version(t), sig)] = (
-            "wprice", "sprice", "coloc", "max_w", "max_s")
-    warm = pol._warm_for(win, rel)
-    assert warm is not None and len(warm) == win.lookahead - rel.arrival
-    # the reshaped job: same job_id, same slots, same slot versions —
-    # different demand signature
-    reshaped = win.rel_job(job.at_level(2))
-    assert pol._bundle_sig(win, reshaped) != sig
-    assert pol._warm_for(win, reshaped) is None
-    # unchanged-signature re-offer still hits (the fix must not overcull)
-    assert pol._warm_for(win, rel) is not None
 
 
 # --------------------------------------------------- recovery parity

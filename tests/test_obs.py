@@ -56,7 +56,7 @@ def small_job(job_id=0, arrival=0, V=2000, F=16, gamma=2.0, **kw):
 
 def _fingerprint(records):
     """Full decision fingerprint: admission, utility, and the exact
-    committed slot allocations (same tuple bench_scheduler compares)."""
+    committed slot allocations."""
     out = []
     for r in records:
         slots = None

@@ -19,7 +19,7 @@ from __future__ import annotations
 import pytest
 from hypothesis import given, settings
 
-from repro.sim import RollingWindow, SimEngine, make_policy
+from repro.sim import RollingWindow, SimEngine, TraceConfig, make_policy
 from repro.core import make_cluster
 from repro.sim.metrics import MetricsCollector
 
@@ -61,6 +61,19 @@ def test_batched_equiv_same_slot_collisions(seed):
 def test_batched_equiv_pdors(faults, metrics_mode):
     _assert_equivalent("pdors", 3, num_jobs=40, faults=faults,
                        metrics_mode=metrics_mode)
+
+
+@pytest.mark.parametrize("rng_mode", ["derived", "compat"])
+def test_batched_equiv_pdors_reoffer_heavy(rng_mode):
+    """Heavy job-failure and re-fail churn on a small cluster: preempted
+    jobs are re-offered over and over, each re-offer building a fresh
+    plan against a ledger that only partly changed since the last one."""
+    tcfg = TraceConfig(num_jobs=60, seed=4, arrival_rate=5.0,
+                       failure_rate=0.4)
+    r1, _ = _assert_equivalent("pdors", 4, trace_cfg=tcfg, refail=0.4,
+                               max_slots=2000,
+                               policy_kwargs={"rng_mode": rng_mode})
+    assert r1.summary["preemptions"] > 0
 
 
 @pytest.mark.parametrize("policy", ["fifo", "dorm"])

@@ -473,44 +473,3 @@ def test_offer_with_stale_plan_patches_decision_identical(rng_mode):
     sched2.offer(jobs[0])
     rec1b = sched2.offer(jobs[1])
     assert _decisions([rec1]) == _decisions([rec1b])
-
-
-def test_warm_bundle_reoffers_bit_identical():
-    """Sim-level requeue/preempt re-offers: the PDORS policy's warm
-    bundle store (slot-version-keyed reuse of the fused decision
-    bundles) must leave every decision bit-identical to a run with the
-    store disabled — and must actually get hits on a faulty trace."""
-    from repro.obs.metrics import get_registry
-    from repro.sim import (
-        RollingWindow, SimEngine, TraceConfig,
-        calibrate_prices, make_policy, stream,
-    )
-
-    def run(disable_warm):
-        # clean trace, heavy job-failure/re-fail churn: machine incidents
-        # stamp every ledger row (set_capacity_mask), so chaos traces
-        # rarely reuse bundles — job-level re-offers are the hit path
-        tcfg = TraceConfig(num_jobs=60, seed=4, arrival_rate=5.0,
-                           failure_rate=0.4)
-        cl = make_cluster(6, 12)
-        win = RollingWindow(cl)
-        pol = make_policy("pdors",
-                          price_params=calibrate_prices(tcfg, cl, n=16),
-                          quanta=8)
-        if disable_warm:
-            pol._warm_for = lambda view, rel: None
-            pol._harvest_bundles = lambda view, rel, plan: None
-        eng = SimEngine(win, pol, seed=4, max_slots=2000,
-                        patience=tcfg.patience, engine_mode="batched",
-                        refail_rate=0.4)
-        rep = eng.run(stream(tcfg))
-        return rep, eng
-
-    before = get_registry().value("repro_warm_bundle_hits_total")
-    r_warm, e_warm = run(disable_warm=False)
-    assert get_registry().value("repro_warm_bundle_hits_total") > before
-    r_cold, e_cold = run(disable_warm=True)
-    assert r_warm.summary == r_cold.summary
-    assert np.array_equal(np.asarray(e_warm.window.cluster._used),
-                          np.asarray(e_cold.window.cluster._used))
-    assert e_warm.journal == e_cold.journal
