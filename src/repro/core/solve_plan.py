@@ -584,8 +584,10 @@ class SolvePlan:
                     keys.append(key)
             rsp.set(rounded=len(work))
             with _trace.span("plan.finish", candidates=len(work)) as fsp:
-                rows, cells = self._finish_batched(work, keys, memo)
-                fsp.set(head_rows=rows, head_cells=cells)
+                rows, cols, cells, widened = self._finish_batched(
+                    work, keys, memo)
+                fsp.set(head_rows=rows, head_cells=cells, head_cols=cols,
+                        head_widened=widened)
 
     def _g_delta(self, p: _Pending) -> float:
         """G_delta for one candidate (Theorems 3-4) — the branch
@@ -604,25 +606,25 @@ class SolvePlan:
         work: List[Tuple[_Pending, np.ndarray]],
         keys: List[Tuple[int, int]],
         memo: Dict[Tuple[int, int], Optional[ThetaResult]],
-    ) -> Tuple[int, int]:
+    ) -> Tuple[int, int, int, int]:
         """The rng-free tail of ``_external_finish`` over every candidate
         in ONE stacked pass: rounding feasibility for all candidates of
         all subset sizes and slots together (machine-padded — padding is
         neutral because the padded packing cells evaluate to 0 and
         ``pack_v`` is clamped at 0 anyway, and padded worker cells add
         exact zeros to the integer-exact sums), clip detection on the
-        loaded cells only, head-room rows from per-slot zero-load rows
-        with the loaded cells recomputed (``_headroom_rows``), and the
-        cover/ratio prefix fills over the whole candidate set with
-        per-candidate price orders gathered row-wise.  Only candidates
-        whose clip phase actually fires (rare) fall back to the scalar
-        ``_repair``.  Results are bit-identical to the per-candidate
-        finish — covered by the plan-vs-loop parity tests.  Returns the
-        head-room work done, (zero-load slot rows, loaded cells), summed
-        over both passes."""
-        head_rows = head_cells = 0
+        loaded cells only, and the cover/ratio fills over the whole
+        candidate set, each on a prefix of its slot's price order that
+        widens only where it cannot cover the need (``_prefix_fill``).
+        Only candidates whose clip phase actually fires (rare) fall back
+        to the scalar ``_repair``.  Results are bit-identical to the
+        per-candidate finish — covered by the plan-vs-loop parity tests.
+        Returns the head-room work done, summed over both passes:
+        zero-load slot rows, zero-load cells (the prefix columns of those
+        rows), loaded cells recomputed, and the number of candidates whose
+        first prefix did not cover a need."""
         if not work:
-            return head_rows, head_cells
+            return 0, 0, 0, 0
         cfg, job = self.cfg, self.job
         S = cfg.rounding_rounds
         batch_cap = float(job.batch_size)
@@ -641,6 +643,8 @@ class SolvePlan:
         WOD = np.stack([self.snaps[t].wprice_order_desc for t in uniq_ts])
         SO = np.stack([self.snaps[t].sprice_order for t in uniq_ts])
         si = np.array([tpos[p.t] for p, _ in work], dtype=np.int64)
+        head = np.zeros(3, dtype=np.int64)       # rows, cols, cells
+        widened = np.zeros(n_work, dtype=bool)
 
         # ---- rounding selection, fused across subset sizes -------------
         # every round's feasibility is independent of the other rounds,
@@ -747,9 +751,9 @@ class SolvePlan:
         # ---- repair (infeasible roundings), one stacked pass -----------
         # the whole greedy repair collapses to: clip detection (batched
         # over the loaded cells of every candidate of every slot at
-        # once; an empty machine cannot clip), head-room rows (per-slot
-        # rows, loaded cells recomputed), and the closed-form prefix
-        # fill; only candidates whose clip phase actually fires (rare)
+        # once; an empty machine cannot clip) and the closed-form fill
+        # over each slot's cheapest machines (``_prefix_fill``); only
+        # candidates whose clip phase actually fires (rare)
         # fall back to the scalar ``_repair``, which re-derives
         # everything after clipping
         need_repair = np.flatnonzero(~rfeas)
@@ -776,21 +780,15 @@ class SolvePlan:
                 wsum1 = Wc.sum(axis=1)
                 need = np.ceil(W1c - wsum1).astype(np.int64)
                 budget = (job.batch_size - wsum1).astype(np.int64)
-                heads, rows, cells = _headroom_rows(
-                    snap0.head_aux("w"), "w", F, si[idx], Wc, Sc
-                )
-                head_rows += rows
-                head_cells += cells
+                # a met cover (need <= 0) or a spent budget gives X <= 0:
+                # nothing is placed
                 X = np.minimum(need, budget)
-                order = WO[si[idx]]                      # (C, H) per-slot
-                hv = np.minimum(np.take_along_axis(heads, order, 1),
-                                np.maximum(X, 0)[:, None])
-                prefix = np.cumsum(hv, axis=1) - hv
-                takes = np.clip(X[:, None] - prefix, 0, hv)
-                takes[need <= 0] = 0              # cover already satisfied
-                ci = np.arange(clean.size)
-                Wc[ci[:, None], order] += takes
-                fail = (need > 0) & (need - takes.sum(axis=1) > 0)
+                filled, wid, work_done = _prefix_fill(
+                    snap0.head_aux("w"), "w", F, WO, si[idx], Wc, Sc, X
+                )
+                head += work_done
+                widened[idx[wid]] = True
+                fail = (need > 0) & (need - filled > 0)
                 for c, i in enumerate(idx):
                     i = int(i)
                     if fail[c]:
@@ -819,19 +817,12 @@ class SolvePlan:
             if todo.size:
                 idx = alive[todo]
                 Wc, Sc, needc = Wst[todo], Sst[todo], need[todo]
-                heads, rows, cells = _headroom_rows(
-                    snap0.head_aux("s"), "s", F, si[idx], Wc, Sc
+                filled, wid, work_done = _prefix_fill(
+                    snap0.head_aux("s"), "s", F, SO, si[idx], Wc, Sc, needc
                 )
-                head_rows += rows
-                head_cells += cells
-                order = SO[si[idx]]
-                hv = np.minimum(np.take_along_axis(heads, order, 1),
-                                needc[:, None])
-                prefix = np.cumsum(hv, axis=1) - hv
-                takes = np.clip(needc[:, None] - prefix, 0, hv)
-                ci = np.arange(todo.size)
-                Sc[ci[:, None], order] += takes
-                fail = needc - takes.sum(axis=1) > 0
+                head += work_done
+                widened[idx[wid]] = True
+                fail = needc - filled > 0
                 for c, i in enumerate(idx):
                     ss[int(i)] = None if fail[c] else Sc[c]
 
@@ -855,17 +846,23 @@ class SolvePlan:
             cands = [c for c in (p.internal, ext) if c is not None]
             memo[keys[i]] = (min(cands, key=lambda r: r.cost)
                              if cands else None)
-        return head_rows, head_cells
+        rows, cols, cells = (int(x) for x in head)
+        return rows, cols, cells, int(widened.sum())
 
 
 def _headroom_rows(aux: tuple, kind: str, F: np.ndarray, slots: np.ndarray,
-                   W: np.ndarray, S: np.ndarray) -> Tuple[np.ndarray, int, int]:
-    """Head-room rows ((C, H)) for candidates at loads ``(W, S)``, each
-    on the free matrix of its slot, ``F[slots[c]]`` (``F`` stacks the
-    slot free matrices, (U, H, R)); ``aux`` is a snapshot's ``head_aux
-    (kind)``, whose demand operands all slots of a job share.  Returns
-    the rows, the number of zero-load slot rows computed and the number
-    of loaded cells recomputed.
+                   W: np.ndarray, S: np.ndarray,
+                   cols: Optional[np.ndarray] = None,
+                   ) -> Tuple[np.ndarray, int, int]:
+    """Head-room rows for candidates at loads ``(W, S)``, each on the
+    free matrix of its slot, ``F[slots[c]]`` (``F`` stacks the slot free
+    matrices, (U, H, R)); ``aux`` is a snapshot's ``head_aux(kind)``,
+    whose demand operands all slots of a job share.  Without ``cols`` the
+    rows span all H machines ((C, H) loads and result); with ``cols``
+    ((U, K) machine indices per slot) candidate c's row covers only the
+    machines ``cols[slots[c]]`` and ``(W, S)`` are its loads there, (C,
+    K).  Returns the rows, the number of zero-load slot rows computed and
+    the number of loaded cells recomputed.
 
     A machine a candidate leaves empty has the head-room of its slot's
     zero-load row, so that row is computed once per slot the candidates
@@ -873,7 +870,8 @@ def _headroom_rows(aux: tuple, kind: str, F: np.ndarray, slots: np.ndarray,
     gathered free rows.  ``_headroom_from_aux`` is elementwise over the
     cells and every cell gets the same operands (the slot's free row with
     the same ``+ 1e-9`` shift), so the result is bit-identical to one
-    dense call over the (C, H, R) stack of ``F[slots]``."""
+    dense call over the (C, H, R) stack of ``F[slots]``, read at the
+    columns."""
     pos, dpos, _fp, wdp, sdp, wdn, sdn, _fn = aux
     nonpos = ~pos
     has_non = bool(nonpos.any())
@@ -883,14 +881,76 @@ def _headroom_rows(aux: tuple, kind: str, F: np.ndarray, slots: np.ndarray,
         return (pos, dpos, Fx[..., pos] + 1e-9, wdp, sdp, wdn, sdn, fnon)
 
     used, inv = np.unique(slots, return_inverse=True)
-    zero = np.zeros((used.size, F.shape[1]), dtype=np.int64)
-    heads = _headroom_from_aux(shifted(F[used]), kind, zero, zero)[inv]
-    c, h = np.nonzero((W != 0) | (S != 0))
+    Fz = F[used] if cols is None else F[used[:, None], cols[used]]
+    zero = np.zeros(Fz.shape[:2], dtype=np.int64)
+    heads = _headroom_from_aux(shifted(Fz), kind, zero, zero)[inv]
+    c, k = np.nonzero((W != 0) | (S != 0))
     if c.size:
-        heads[c, h] = _headroom_from_aux(
-            shifted(F[slots[c], h]), kind, W[c, h], S[c, h]
+        h = k if cols is None else cols[slots[c], k]
+        heads[c, k] = _headroom_from_aux(
+            shifted(F[slots[c], h]), kind, W[c, k], S[c, k]
         )
     return heads, int(used.size), int(c.size)
+
+
+#: columns of the first price-order prefix a fill reads; each widening
+#: doubles it (up to H) for the candidates it did not cover
+_FILL_K0 = 8
+
+
+def _prefix_fill(aux: tuple, kind: str, F: np.ndarray, order: np.ndarray,
+                 slots: np.ndarray, W: np.ndarray, S: np.ndarray,
+                 X: np.ndarray) -> Tuple[np.ndarray, np.ndarray, tuple]:
+    """The closed-form greedy fill of ``_repair`` (kind "w", adding
+    workers to ``W``) or ``_ensure_ratio`` ("s", adding servers to
+    ``S``), in place, for every candidate c at once: place ``X[c]`` units
+    along its slot's price order ``order[slots[c]]``, taking ``min(head,
+    remaining)`` on each machine with head-room at the entry loads.
+    Returns the units placed a candidate, which candidates the first
+    prefix did not cover, and the head-room work (zero-load slot rows,
+    zero-load cells, loaded cells recomputed).
+
+    The takes are the dense fill's over all H columns, ``hv = min(head,
+    X)``, ``takes = clip(X - (cumsum(hv) - hv), 0, hv)``: every column
+    past the one where the running sum reaches X takes 0.  So the fill
+    runs over a prefix of ``_FILL_K0`` columns and widens (doubling, up to
+    H) only for the candidates whose prefix holds less than their need,
+    carrying the rest of the need into the new columns; a candidate no
+    column can cover reaches H.  Each column's head-room reads only its
+    own entry loads, which no earlier column's takes touch, and is
+    computed by ``_headroom_rows`` on the same operands as the dense
+    rows.  Integer arithmetic throughout: the takes are the dense
+    fill's exactly."""
+    H = order.shape[1]
+    tgt = W if kind == "w" else S
+    rem = np.maximum(X, 0)
+    widened = np.zeros(slots.size, dtype=bool)
+    act = np.flatnonzero(rem > 0)
+    rows = ncols = cells = 0
+    k0, k1 = 0, min(_FILL_K0, H)
+    while act.size:
+        ocols = order[:, k0:k1]                  # (U, K) per-slot columns
+        sl = slots[act]
+        hcols = ocols[sl]                        # (A, K)
+        ai = act[:, None]
+        heads, r, nc = _headroom_rows(aux, kind, F, sl, W[ai, hcols],
+                                      S[ai, hcols], ocols)
+        rows = rows or r                         # first prefix: every slot
+        ncols += r * (k1 - k0)
+        cells += nc
+        ra = rem[act]
+        hv = np.minimum(heads, ra[:, None])
+        prefix = np.cumsum(hv, axis=1) - hv
+        takes = np.clip(ra[:, None] - prefix, 0, hv)
+        tgt[ai, hcols] += takes
+        rem[act] = ra - takes.sum(axis=1)
+        act = act[rem[act] > 0]
+        if k0 == 0:
+            widened[act] = True
+        if k1 == H:
+            break
+        k0, k1 = k1, min(2 * k1, H)
+    return np.maximum(X, 0) - rem, widened, (rows, ncols, cells)
 
 
 def solve_plans(plans: List[SolvePlan]) -> None:

@@ -140,7 +140,10 @@ def test_plan_finish_span_counts_headroom_work():
     """Each plan.finish span says how much head-room work its finish did:
     at most two zero-load rows a slot (one a pass) and two loaded cells a
     candidate machine. On a light cluster the candidates load few of the
-    machines, so the loaded cells are far below candidates x H."""
+    machines, so the loaded cells are far below candidates x H. The
+    fills read only a prefix of each slot's price order, so the
+    zero-load columns computed are far below the dense 2 x slots x H, and
+    at most every candidate widens its first prefix."""
     H, T = 64, 10
     tracer = Tracer()
     _run_offers(H, T, 8, 0.05, "compat", tracer=tracer)
@@ -150,10 +153,15 @@ def test_plan_finish_span_counts_headroom_work():
         cands = at["candidates"]
         assert 0 <= at["head_rows"] <= 2 * min(cands, T)
         assert 0 <= at["head_cells"] <= 2 * cands * H
+        assert 0 <= at["head_cols"] <= 2 * cands * H
+        assert 0 <= at["head_widened"] <= cands
     cands = sum(at["candidates"] for at in finishes)
     cells = sum(at["head_cells"] for at in finishes)
     assert cands > 0 and sum(at["head_rows"] for at in finishes) > 0
     assert 0 < cells < cands * H / 4
+    cols = sum(at["head_cols"] for at in finishes)
+    dense = sum(2 * min(at["candidates"], T) * H for at in finishes)
+    assert 0 < cols < dense / 8
 
 
 # ------------------------------------------- exception well-formedness

@@ -254,6 +254,90 @@ def test_headroom_rows_match_dense_stack(kind, case):
             assert (got[:, 1::2][W[:, 1::2] >= 2] == 0).all()
 
 
+FILL_CASES = ["covered", "full_prefix", "uncoverable", "loaded_in_out",
+              "guard"]
+
+
+@pytest.mark.parametrize("kind", ["w", "s"])
+@pytest.mark.parametrize("case", FILL_CASES)
+def test_prefix_fill_matches_dense_fill(kind, case):
+    """The fill over a widening prefix of each slot's price order gives
+    exactly the dense (C, H) fill: the same takes, the same ``fail`` and
+    the same final loads, when the first prefix covers the need, when it
+    is all full machines (widening), when no column can cover the need (K
+    reaches H), with loaded cells inside and outside the prefix, and with
+    the zero-demand guard column."""
+    from repro.core.solve_plan import _FILL_K0, _prefix_fill
+    from repro.core.subproblem import PriceSnapshot
+
+    H, T, U, C = 40, 6, 3, 10
+    jobs = synthetic_jobs(WorkloadConfig(num_jobs=6, horizon=T, seed=2,
+                                         batch=(50, 200),
+                                         workload_scale=0.2))
+    job = jobs[1] if case == "guard" and kind == "s" else jobs[0]
+    cluster = make_cluster(H, T)
+    prices = PriceTable(estimate_price_params(jobs, cluster, T), cluster)
+    snap = PriceSnapshot(job, cluster, prices, 0)
+    aux = snap.head_aux(kind)
+    rng = np.random.default_rng(FILL_CASES.index(case))
+    R = snap.wdem.size
+    F = np.broadcast_to(snap.free_mat, (U, H, R)).copy()
+    order = np.stack([rng.permutation(H) for _ in range(U)])
+    slots = rng.integers(0, U, C)
+    slots[:U] = np.arange(U)                 # every slot is used
+    W = np.zeros((C, H), dtype=np.int64)
+    S = np.zeros((C, H), dtype=np.int64)
+    X = rng.integers(1, 4, C)
+    if case == "full_prefix":
+        for u in range(U):                   # the cheapest 2 K0 are full
+            F[u, order[u, :2 * _FILL_K0]] = 0.0
+    if case == "uncoverable":
+        X = np.full(C, 10 ** 6)
+        X[0] = -2                            # a met need places nothing
+    if case == "loaded_in_out":
+        for c in range(C):
+            o = order[slots[c]]
+            cells = np.r_[o[:3], o[-3:]]     # inside and past the prefix
+            W[c, cells] = rng.integers(0, 3, cells.size)
+            S[c, cells] = rng.integers(0, 2, cells.size)
+        X = rng.integers(1, 40, C)
+    if case == "guard":
+        gpu = cluster.resources.index("gpu")
+        assert not aux[0][gpu]   # gpu is a zero-demand column of the kind
+        F[:, ::2, gpu] = -1.0    # negative free gpu on every other machine
+        F[:, 1::2, gpu] = 1.5    # fits one worker's gpu, not two
+        W[:, ::3] = rng.integers(0, 3, (C, W[:, ::3].shape[1]))
+        X = rng.integers(1, 30, C)
+
+    # the dense oracle: head-room over all H columns, filled in price order
+    heads = _dense_heads(aux, kind, F, slots, W, S)
+    o = order[slots]
+    hv = np.minimum(np.take_along_axis(heads, o, 1),
+                    np.maximum(X, 0)[:, None])
+    takes = np.clip(X[:, None] - (np.cumsum(hv, axis=1) - hv), 0, hv)
+    Wd, Sd = W.copy(), S.copy()
+    (Wd if kind == "w" else Sd)[np.arange(C)[:, None], o] += takes
+    want_fill = takes.sum(axis=1)
+
+    Wp, Sp = W.copy(), S.copy()
+    filled, widened, (rows, cols, cells) = _prefix_fill(
+        aux, kind, F, order, slots, Wp, Sp, X)
+    assert np.array_equal(filled, want_fill)
+    assert np.array_equal(X - filled > 0, X - want_fill > 0)   # fail
+    assert np.array_equal(Wp, Wd) and np.array_equal(Sp, Sd)
+    assert rows == U and _FILL_K0 * U <= cols <= H * U
+    if case == "covered":
+        assert not widened.any() and cols == _FILL_K0 * U
+    if case == "full_prefix":
+        assert widened.all() and (filled == X).all()
+    if case == "uncoverable":
+        assert (widened == (X > 0)).all() and cols == H * U
+        assert (X - filled > 0)[1:].all() and filled[0] == 0
+    if case == "loaded_in_out":
+        # only the loaded cells of the columns a candidate's fill read
+        assert 0 < cells < int(((W != 0) | (S != 0)).sum())
+
+
 def test_fused_bundle_batch_matches_per_slot_numpy():
     """The fused (W, H) bundle pass must be bit-identical to W per-slot
     reductions on the numpy backend."""
